@@ -26,7 +26,7 @@ import os
 import subprocess
 import sys
 
-from .common import emit, write_json
+from .common import emit, require_cpu_parent, write_json
 
 _MARK = "@@SCALING@@ "
 
@@ -65,12 +65,12 @@ def _worker(cfg: dict) -> None:
     }))
 
 
-def _spawn(cfg: dict, n_devices: int) -> dict | None:
+def _spawn(cfg: dict, n_devices: int) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={n_devices}"
                         ).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_scaling",
          "--worker", json.dumps(cfg)],
@@ -79,12 +79,13 @@ def _spawn(cfg: dict, n_devices: int) -> dict | None:
     for line in proc.stdout.splitlines():
         if line.startswith(_MARK):
             return json.loads(line[len(_MARK):])
-    print(f"# worker failed (devices={n_devices}): "
-          f"{proc.stderr.strip().splitlines()[-1] if proc.stderr else '?'}")
-    return None
+    raise RuntimeError(
+        f"scaling worker failed (devices={n_devices}): "
+        f"{proc.stderr.strip().splitlines()[-1] if proc.stderr else '?'}")
 
 
 def run(fast: bool = False):
+    require_cpu_parent("bench_scaling")
     strong = dict(SMOKE_STRONG if fast else STRONG, n_cand=1)
     weak = dict(SMOKE_WEAK if fast else WEAK, n_cand=1)
     devices = SMOKE_DEVICES if fast else DEVICES
@@ -93,8 +94,6 @@ def run(fast: bool = False):
     strong_arms = []
     for n in devices:
         rec = _spawn(strong, n)
-        if rec is None:
-            continue
         strong_arms.append(rec)
         rows.append((f"scaling_strong/{strong['dataset']}/devices={n}",
                      round(rec["wall"] * 1e6 / rec["n_txns"], 3),
@@ -104,8 +103,6 @@ def run(fast: bool = False):
     for n in devices:
         cfg = dict(weak, scale=weak["scale_per_device"] * n)
         rec = _spawn(cfg, n)
-        if rec is None:
-            continue
         weak_arms.append(rec)
         rows.append((f"scaling_weak/{weak['dataset']}/devices={n}",
                      round(rec["wall"] * 1e6 / rec["n_txns"], 3),

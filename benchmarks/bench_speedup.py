@@ -13,7 +13,7 @@ import os
 import subprocess
 import sys
 
-from .common import emit
+from .common import emit, require_cpu_parent
 
 _CHILD = r"""
 import json, time, sys
@@ -35,6 +35,7 @@ print(json.dumps({"wall": wall, "devices": len(jax.devices()),
 
 
 def run(fast: bool = False):
+    require_cpu_parent("bench_speedup")
     rows = []
     counts = [1, 4] if fast else [1, 2, 4, 8]
     for algo in ["vfpc", "optimized_vfpc"] if not fast else ["optimized_vfpc"]:
@@ -43,6 +44,7 @@ def run(fast: bool = False):
         for n in counts:
             env = dict(os.environ)
             env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
+            env["JAX_PLATFORMS"] = "cpu"
             env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
             r = subprocess.run([sys.executable, "-c", _CHILD % algo],
                                capture_output=True, text=True, env=env,

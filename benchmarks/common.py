@@ -21,6 +21,18 @@ DATASETS = {
 }
 
 
+def require_cpu_parent(suite: str) -> None:
+    """Refuse to run a suite that simulates devices in child processes
+    unless this process is on the CPU: a parent on an accelerator holds the
+    chip, and CPU children would report host-simulated numbers beside it."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{suite} simulates devices on the CPU in child processes; this "
+            f"process is on {backend!r} — run it with JAX_PLATFORMS=cpu")
+
+
 def load(name: str, scale=None, seed: int = 0):
     return dataset_by_name(name, seed=seed, scale=scale or DATASETS[name]["scale"])
 

@@ -47,14 +47,18 @@ def main() -> None:
     suites = {args.only: SUITES[args.only]} if args.only else (
         {k: SUITES[k] for k in SMOKE_SUITES} if args.smoke else SUITES)
     t0 = time.time()
+    failed = []
     for name, mod in suites.items():
         print(f"== {name} ==", flush=True)
         try:
             mod.run(fast=args.fast)
-        except Exception as e:  # keep the suite going; a failed bench is loud
+        except Exception as e:  # finish the other suites, then exit non-zero
+            failed.append(name)
             print(f"name,us_per_call,derived\n{name}/FAILED,0,{type(e).__name__}: {e}\n",
                   flush=True)
     print(f"# total benchmark wall time: {time.time() - t0:.1f}s")
+    if failed:
+        sys.exit(f"benchmark suites failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

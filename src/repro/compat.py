@@ -1,38 +1,18 @@
-"""Version shims over JAX API drift.
+"""Mesh construction shared by the runtime, launchers and tests.
 
-The runtime targets both current JAX (``jax.shard_map``, ``jax.make_mesh``
-with ``axis_types``, ``check_vma``) and the 0.4.x line still common on
-clusters (``jax.experimental.shard_map.shard_map`` with ``check_rep``, no
-``jax.sharding.AxisType``).  Everything that builds meshes or shard_maps goes
-through here so the rest of the codebase is version-agnostic.
+``jax.make_mesh`` defaults to Explicit axis types; the runtime's shard_maps
+and ``NamedSharding`` placements are written for Auto axes, so every mesh is
+built here with Auto axis types.
 """
 
 from __future__ import annotations
 
 import jax
 
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-_HAS_TOP_LEVEL_SHARD_MAP = hasattr(jax, "shard_map")
 
-
-def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            axis_shapes, axis_names,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` on new JAX, the experimental one on 0.4.x.
-
-    ``check_vma`` maps onto the old ``check_rep`` flag (same semantics for our
-    usage: skip the replication/varying-manual-axes check).
-    """
-    if _HAS_TOP_LEVEL_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
+def make_mesh(axis_shapes, axis_names, devices=None):
+    """``jax.make_mesh`` with Auto axis types (optionally over ``devices``)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)

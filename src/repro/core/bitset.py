@@ -246,6 +246,16 @@ def junpack_bits(masks: jax.Array) -> jax.Array:
     return bits.reshape(*m.shape[:-1], m.shape[-1] * WORD_BITS).astype(jnp.int8)
 
 
+def junpack_bits_lanes(masks: jax.Array) -> jax.Array:
+    """:func:`junpack_bits` with the word axis zero-padded to a multiple of 4,
+    so the bit-plane axis is a multiple of 128 lanes — the int8 tiling the
+    Pallas matmul kernels need.  Zero planes add nothing to any overlap."""
+    pad = (-masks.shape[-1]) % 4
+    if pad:
+        masks = jnp.pad(masks, [(0, 0)] * (masks.ndim - 1) + [(0, pad)])
+    return junpack_bits(masks)
+
+
 def jpack_bits(bits: jax.Array) -> jax.Array:
     """Inverse of :func:`junpack_bits`: ``(..., B) int8/bool → (..., ceil(B/32))
     uint32`` (B is zero-padded up to the word multiple)."""

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 from repro.kernels.support_count import (support_count_matmul,
                                          support_count_matmul_pallas,
                                          support_count_pallas)
-from repro.kernels.ops import _empty_cand_correction, _support_count_jnp
+from repro.kernels.ops import (_empty_cand_correction, _support_count_jnp,
+                               pallas_interpret)
 from repro.kernels.vertical_count import (DEFAULT_BLOCK, DEFAULT_BT,
                                           vertical_count_jnp,
                                           vertical_count_matmul,
@@ -54,7 +55,7 @@ def local_counts(db_local: jax.Array, cands: jax.Array, impl: str,
         fn = (support_count_matmul_pallas if impl.startswith("matmul")
               else support_count_pallas)
         out = fn(cands, db_local, bc=bc, bt=bt,
-                 interpret=impl.endswith("_interpret"))
+                 interpret=pallas_interpret(impl))
         return out - _empty_cand_correction(cands, pad)
     raise ValueError(f"unknown impl {impl!r}")
 
@@ -79,11 +80,11 @@ def local_counts_vertical(vdb_local: jax.Array, cand_idx: jax.Array,
     """
     if impl in ("pallas", "pallas_interpret"):
         return vertical_count_pallas(vdb_local, cand_idx, bt=bt,
-                                     interpret=(impl == "pallas_interpret"))
+                                     interpret=pallas_interpret(impl))
     if impl in ("matmul_pallas", "matmul_pallas_interpret"):
         return vertical_count_matmul_pallas(
             vdb_local, cand_idx, bc=bc, bt=bt,
-            interpret=impl.endswith("_interpret"))
+            interpret=pallas_interpret(impl))
     if impl == "matmul":
         return vertical_count_matmul(vdb_local, cand_idx, block=block)
     if impl == "jnp":

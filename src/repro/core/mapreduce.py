@@ -48,8 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
-from repro.kernels.autotune import tuned_blocks
+from repro.compat import make_mesh
+from repro.kernels.autotune import defaults_fit, tuned_blocks
 from repro.obs.metrics import get_registry
 
 from .counting import local_counts, local_counts_vertical
@@ -125,12 +125,9 @@ class CountFuture:
         self.wait_seconds = 0.0   # host time actually blocked in result()
 
     def ready(self) -> bool:
-        """Best-effort non-blocking completion probe."""
-        try:
-            return all(leaf.is_ready()
-                       for leaf in jax.tree_util.tree_leaves(self._raw))
-        except AttributeError:      # very old jax.Array without is_ready
-            return True
+        """Non-blocking completion probe."""
+        return all(leaf.is_ready()
+                   for leaf in jax.tree_util.tree_leaves(self._raw))
 
     def result(self):
         if self._result is None:
@@ -265,6 +262,9 @@ class MapReduceRuntime:
                               T=max(n // self.n_data_shards, 1), W=w, kmax=4)
             if plan is not None and plan["impl"] in IMPLS:
                 self.impl = plan["impl"]
+        elif (self._auto_impl and self.impl == "pallas"
+              and not defaults_fit("pallas", w)):
+            self.impl = "jnp"     # the static TPU fallback, too wide for VMEM
         d = self.n_data_shards
         pad = (-n) % d
         if pad:
@@ -335,8 +335,11 @@ class MapReduceRuntime:
             if not self.autotune:
                 return dict(DEFAULTS[impl_key])
             C, kmax = payload_shape
+            # W is the mask width ceil(I/32), as in the plan that chose the
+            # family, so both price the same (I+1)-row blocks
             return tuned_blocks(impl_key, C=max(C // dc, 1), T=db_shape[-1],
-                                W=db_shape[-2] // 32 + 1, kmax=kmax)
+                                W=max(-(-(db_shape[-2] - 1) // 32), 1),
+                                kmax=kmax)
         if not self.autotune:
             return dict(DEFAULTS[self.impl])
         C, W = payload_shape
@@ -399,8 +402,8 @@ class MapReduceRuntime:
             in_specs = (db_spec, cand_spec)
             out_specs = out_spec
 
-        fn = shard_map(mapper, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(mapper, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return jax.jit(fn)
 
     def _padded_indices(self, masks: np.ndarray) -> np.ndarray:

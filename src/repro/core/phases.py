@@ -38,36 +38,30 @@ from .mapreduce import MapReduceRuntime
 MIN_BUCKET = 256
 
 
-def _impl_family(impl: str) -> str:
-    """Map a runtime impl name to its roofline kernel family."""
-    if "matmul" in impl:
-        return "matmul"
-    if impl.startswith("vertical"):
-        return "vertical"
-    return "horizontal"
-
-
 def count_roofline_attrs(runtime: MapReduceRuntime, n_candidates: int,
                          n_txns: int, n_words: int, kmax: int,
                          seconds: float) -> dict:
     """Achieved-vs-peak span attributes for one counting job, computed by
-    the same ``roofline.count_kernel_roofline`` that BENCH_kernels.json
-    uses — traces and benchmarks report from one set of numbers
-    (DESIGN.md §10/§13)."""
-    try:
-        import jax
+    the same ``roofline.count_kernel_roofline`` the kernel benchmark uses —
+    traces and benchmarks report from one set of numbers (DESIGN.md §10/§13).
+    The job's work is compared against the peaks of every chip in the
+    runtime's mesh.  A CPU run, or an interpret-mode Pallas run, has no
+    device roofline and emits none; any other device must have its published
+    peaks in ``roofline.COUNT_PEAKS``."""
+    import jax
 
-        from repro.roofline import count_kernel_roofline
-        roof = count_kernel_roofline(
-            _impl_family(runtime.impl), C=n_candidates, T=n_txns,
-            W=n_words, kmax=kmax, seconds=max(seconds, 1e-9),
-            backend=jax.default_backend())
-        return {"roofline_bound": roof["bound"],
-                "roofline_achieved": roof["achieved"],
-                "roofline_peak": roof["peak"],
-                "roofline_peak_frac": roof["peak_frac"]}
-    except Exception:   # uncalibrated peaks table / exotic backend
+    from repro.roofline import count_kernel_roofline
+    dev = jax.devices()[0]
+    if dev.platform == "cpu" or runtime.impl.endswith("_interpret"):
         return {}
+    roof = count_kernel_roofline(
+        runtime.impl, C=n_candidates, T=n_txns, W=n_words, kmax=kmax,
+        n_items=runtime._n_items, seconds=max(seconds, 1e-9),
+        device_kind=dev.device_kind, chips=runtime.mesh.size)
+    return {"roofline_bound": roof["bound"],
+            "roofline_achieved": roof["achieved"],
+            "roofline_peak": roof["peak"],
+            "roofline_peak_frac": roof["peak_frac"]}
 
 
 def bucket_pad(cands: np.ndarray, min_bucket: int = MIN_BUCKET,

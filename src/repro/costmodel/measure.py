@@ -32,12 +32,8 @@ def device_key(backend: str | None = None) -> str:
     backend — e.g. ``cpu:cpu``, ``tpu:TPU-v5e``, ``gpu:NVIDIA-H100``."""
     import jax
     backend = backend or jax.default_backend()
-    try:
-        kind = jax.devices(backend)[0].device_kind
-    except Exception:
-        kind = "unknown"
-    kind = re.sub(r"[^A-Za-z0-9_.]+", "-", str(kind)).strip("-") or "unknown"
-    return f"{backend}:{kind}"
+    kind = jax.devices(backend)[0].device_kind
+    return f"{backend}:{re.sub(r'[^A-Za-z0-9_.]+', '-', kind).strip('-')}"
 
 
 def time_once(fn, reps: int = 2, clock=None) -> float:

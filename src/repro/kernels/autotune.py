@@ -12,7 +12,12 @@ synthetic data and caches the winner:
 
 ``REPRO_AUTOTUNE=0`` disables timing and returns the static defaults.
 Interpret-mode Pallas (and the Pallas kernels off-TPU generally) are never
-timed: interpret timings are meaningless, so defaults are returned.
+timed: interpret timings are meaningless, so defaults are returned.  The one
+shape check is VMEM: a Pallas block config whose blocks do not fit the budget
+at the mask width (``kernels/tpu_params.py``) is left out of the sweep, and a
+plan names each family it leaves out and why.  Nothing here catches a failed
+compile or run: a kernel that does not build for the device raises instead
+of quietly dropping out of the sweep.
 
 Cache format (DESIGN.md §5, §9)::
 
@@ -304,6 +309,47 @@ def _candidate_runner(impl: str, C: int, T: int, W: int, kmax: int,
     raise ValueError(f"unknown impl {impl!r}")
 
 
+def block_vmem(impl: str, cfg: dict, W: int) -> int:
+    """VMEM bytes the Pallas family ``impl`` needs at block config ``cfg``
+    over W-word masks — the vertical families at up to ``32·W + 1`` item
+    rows; 0 for the jnp families, which hold no blocks in VMEM."""
+    from .delta_count import delta_count_vmem
+    from .rule_match import rule_scores_vmem
+    from .support_count import support_count_vmem
+    from .vertical_count import vertical_count_matmul_vmem, vertical_count_vmem
+    fam = impl.removesuffix("_interpret")
+    mm = "matmul" in fam
+    if fam in ("pallas", "matmul_pallas"):
+        return support_count_vmem(W, cfg["bc"], cfg["bt"], matmul=mm)
+    if fam == "vertical_pallas":
+        return vertical_count_vmem(32 * W + 1, cfg["bt"])
+    if fam == "vertical_matmul_pallas":
+        return vertical_count_matmul_vmem(32 * W + 1, cfg["bc"], cfg["bt"])
+    if fam in ("delta_pallas", "delta_matmul_pallas"):
+        return delta_count_vmem(W, cfg["bc"], cfg["bt"], matmul=mm)
+    if fam in ("rules_pallas", "rules_matmul_pallas"):
+        return rule_scores_vmem(W, cfg["bq"], cfg["br"], matmul=mm)
+    return 0
+
+
+def fitting_configs(impl: str, W: int) -> list[dict]:
+    """The configs of ``CONFIGS[impl]`` whose blocks fit the VMEM budget at
+    W words — the one shape check of the sweeps: most Pallas blocks hold a
+    whole item or bit-plane axis, so wide catalogs leave configs out."""
+    from .tpu_params import VMEM_BUDGET_BYTES
+    return [cfg for cfg in CONFIGS[impl]
+            if block_vmem(impl, cfg, W) <= VMEM_BUDGET_BYTES]
+
+
+def defaults_fit(impl: str, W: int) -> bool:
+    """Whether ``DEFAULTS[impl]`` fit the VMEM budget at W words — the
+    check of the static per-backend fallbacks, which run untimed at the
+    default blocks: on the TPU they take a Pallas kernel only where it fits,
+    else the jnp form."""
+    from .tpu_params import VMEM_BUDGET_BYTES
+    return block_vmem(impl, DEFAULTS[impl], W) <= VMEM_BUDGET_BYTES
+
+
 def tuned_blocks(impl: str, *, C: int, T: int, W: int = 1, kmax: int = 1,
                  backend: str | None = None) -> dict:
     """Best block config for a counting job of the given shape bucket.
@@ -344,17 +390,19 @@ def tuned_blocks(impl: str, *, C: int, T: int, W: int = 1, kmax: int = 1,
         _save_disk(disk)
         return dict(disk[key])
 
+    # configs over the VMEM budget at this width are left out; any other
+    # config that fails to compile or run is a fault and raises
+    configs = fitting_configs(impl, W)
+    if not configs:
+        from .tpu_params import VmemError
+        raise VmemError(f"{impl}: no block config of {CONFIGS[impl]} fits "
+                        f"the VMEM budget at W={W} words; use a jnp impl")
     make = _candidate_runner(impl, _bucket(C), _bucket(T), W, kmax)
     best_cfg, best_t = None, float("inf")
-    for cfg in CONFIGS[impl]:
-        try:
-            t = time_once(make(cfg))
-        except Exception:       # a config can be invalid for exotic shapes
-            continue
+    for cfg in configs:
+        t = time_once(make(cfg))
         if t < best_t:
             best_cfg, best_t = cfg, t
-    if best_cfg is None:
-        best_cfg = DEFAULTS[impl]
     _memory_cache[key] = dict(best_cfg)
     disk[key] = dict(best_cfg)
     _save_disk(disk)
@@ -377,6 +425,25 @@ def _strip_family(kind: str, family: str) -> str:
     return family
 
 
+def plan_families(kind: str, W: int, backend: str) -> tuple[list, dict]:
+    """The families ``tuned_plan`` times for ``kind`` at W-word masks, and
+    those it leaves out by shape, each with why.
+
+    Compiled Pallas kernels exist only on the TPU (interpret-mode timings
+    are meaningless, so off the TPU the Pallas families are not candidates
+    at all); on the TPU a Pallas family none of whose block configs fits
+    the VMEM budget at this width is left out."""
+    families, left_out = [], {}
+    for f in PLAN_FAMILIES[kind]:
+        if "pallas" in f and backend != "tpu":
+            continue
+        if not fitting_configs(f, W):
+            left_out[f] = f"no block config fits the VMEM budget at W={W}"
+            continue
+        families.append(f)
+    return families, left_out
+
+
 def tuned_plan(kind: str, *, C: int, T: int, W: int = 1, kmax: int = 1,
                backend: str | None = None) -> dict | None:
     """Cross-family winner for one shape bucket (DESIGN.md §10).
@@ -390,8 +457,9 @@ def tuned_plan(kind: str, *, C: int, T: int, W: int = 1, kmax: int = 1,
       W:    words per bitmask.
       kmax: items per candidate (prices the vertical gather width).
 
-    Returns ``{"impl": <wrapper impl name>, "blocks": {...}}`` — the measured
-    argmin over every eligible family at its own tuned block sizes, with the
+    Returns ``{"impl": <wrapper impl name>, "blocks": {...}, "left_out":
+    {family: why}}`` — the measured argmin over every eligible family
+    (:func:`plan_families`) at its own tuned block sizes, with the
     jnp baseline always timed (the cross-check that fixes tuned-but-slower
     winners) — or None when ``REPRO_AUTOTUNE=0`` (callers fall back to their
     static per-backend default).  Winners are cached in-process and on disk
@@ -405,8 +473,7 @@ def tuned_plan(kind: str, *, C: int, T: int, W: int = 1, kmax: int = 1,
         raise ValueError(f"unknown plan kind {kind!r}; "
                          f"options: {tuple(PLAN_FAMILIES)}")
     backend = backend or jax.default_backend()
-    families = [f for f in PLAN_FAMILIES[kind]
-                if not ("pallas" in f and backend != "tpu")]
+    families, left_out = plan_families(kind, W, backend)
     baseline = PLAN_BASELINES[kind]
     shape = f"plan/{kind}/C{_bucket(C)}/T{_bucket(T)}/W{W}/k{kmax}"
     key = f"{device_key(backend)}/{shape}"
@@ -419,19 +486,16 @@ def tuned_plan(kind: str, *, C: int, T: int, W: int = 1, kmax: int = 1,
 
     # cost-model pruning: families the calibrated fits price far above the
     # predicted best are skipped (timing still decides among the finalists)
+    from repro.costmodel.model import default_model
+    from repro.roofline import count_job_ops
     predicted: dict[str, float] = {}
-    try:
-        from repro.roofline import count_job_ops
-        from repro.costmodel.model import default_model
-        mdl = default_model()
-        dev = device_key(backend)
-        for fam in families:
-            p = mdl.predict(f"{dev}/{_strip_family(kind, fam)}/count",
-                            count_job_ops(C, T, W))
-            if p is not None and p > 0:
-                predicted[fam] = p
-    except Exception:
-        predicted = {}
+    mdl = default_model()
+    dev = device_key(backend)
+    for fam in families:
+        p = mdl.predict(f"{dev}/{_strip_family(kind, fam)}/count",
+                        count_job_ops(C, T, W))
+        if p is not None and p > 0:
+            predicted[fam] = p
     keep = set(families)
     if len(predicted) >= 2:
         pbest_fam = min(predicted, key=predicted.get)
@@ -448,23 +512,17 @@ def tuned_plan(kind: str, *, C: int, T: int, W: int = 1, kmax: int = 1,
         fc, ft = _family_shape(kind, fam, C, T)
         blocks = tuned_blocks(fam, C=fc, T=ft, W=W, kmax=kmax,
                               backend=backend)
-        try:
-            make = _candidate_runner(fam, _bucket(fc), _bucket(ft), W, kmax,
-                                     cap_c=_PLAN_CAP_C)
-            t = time_once(make(blocks))
-        except Exception:       # a family can be invalid for exotic shapes
-            continue
+        make = _candidate_runner(fam, _bucket(fc), _bucket(ft), W, kmax,
+                                 cap_c=_PLAN_CAP_C)
+        t = time_once(make(blocks))
         timed_us[fam] = t * 1e6
         if t < best_t:
             best_fam, best_blocks, best_t = fam, blocks, t
-    if best_fam is None:        # every family failed: fall back to baseline
-        fc, ft = _family_shape(kind, baseline, C, T)
-        best_fam = baseline
-        best_blocks = tuned_blocks(baseline, C=fc, T=ft, W=W, kmax=kmax,
-                                   backend=backend)
     plan = {"impl": _strip_family(kind, best_fam), "family": best_fam,
-            "blocks": dict(best_blocks), "timed_us": timed_us}
+            "blocks": dict(best_blocks), "timed_us": timed_us,
+            "left_out": left_out}
     _memory_cache[key] = dict(plan)
+    disk = _load_disk()        # re-read: the sweep saved its block choices
     disk[key] = dict(plan)
     _save_disk(disk)
     return dict(plan)

@@ -13,12 +13,16 @@ concatenated into a single ``(T, W)`` slab with a per-row sign vector
 
     delta[i] = Σ_j sign[j] · [cand[i] ⊆ txn[j]]
 
-Tiling mirrors ``support_count.py``: candidates ``(BC, W)`` × slab ``(BT, W)``
-tiles in VMEM, the word loop statically unrolled, an ``(BC,)`` int32
-accumulator revisited across the slab grid axis.  Sign-0 padding makes the
-kernel self-correcting: zero-padded slab rows match empty (zero-padded)
-candidate rows, but contribute 0 — so unlike ``support_count`` no
-empty-candidate correction term is needed on either path.
+Tiling mirrors ``support_count.py``: candidate words ``(W, BC)`` with
+candidates on lanes × slab ``(BT, W)`` tiles in VMEM, the word loop statically
+unrolled, a lane-dense ``(1, BC)`` int32 accumulator revisited across the
+slab grid axis.  The signs enter as one ``(8, T)`` float32 sublane tile (row 0
+the signs, rows 1–7 zero), and the signed reduction over the slab rows is a
+``(8, BT) × (BT, BC)`` matmul — exact, since every product is 0 or ±1.
+Sign-0 padding makes the kernel self-correcting: zero-padded slab rows match
+empty (zero-padded) candidate rows, but contribute 0 — so unlike
+``support_count`` no empty-candidate correction term is needed on either
+path.
 
 The blocked-jnp twin (:func:`delta_count_jnp`) is bit-exact (integer
 arithmetic only) and is the CPU production path; block sizes are autotuned
@@ -35,7 +39,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.kernels.autotune import DEFAULTS, _bucket, tuned_blocks, tuned_plan
+from repro.kernels.autotune import (DEFAULTS, _bucket, defaults_fit,
+                                    tuned_blocks, tuned_plan)
+from repro.kernels.ops import pallas_interpret
+from repro.kernels.tpu_params import COMPILER_PARAMS, check_vmem, vmem_bytes
 
 DEFAULT_BC = 256
 DEFAULT_BT = 256
@@ -47,7 +54,34 @@ DELTA_IMPLS = ("auto", "jnp", "pallas", "pallas_interpret", "matmul",
 MIN_SLAB_BUCKET = 32       # pow2 slab padding floor — few compiled shapes
 
 
-def _delta_count_kernel(c_ref, t_ref, s_ref, o_ref, *, n_words: int):
+def delta_count_vmem(W: int, bc: int, bt: int, matmul: bool = False) -> int:
+    """VMEM bytes of :func:`delta_count_pallas` (with ``matmul``, of
+    :func:`delta_count_matmul_pallas`) at W words and (bc, bt) blocks."""
+    if matmul:
+        B = -(-W // 4) * 128                 # bit planes, padded to 128 lanes
+        # plus a copy of each int8 operand block that Mosaic makes for the
+        # dot at most widths, and the (bt, bc) int32 product tile
+        return vmem_bytes([((B, bc), 1), ((1, bc), 4), ((bt, B), 1),
+                           ((8, bt), 4), ((1, bc), 4)],
+                          scratch=[((B, bc), 1), ((bt, B), 1), ((bt, bc), 4)])
+    # the unrolled word loop's lane-padded (bt, 1) column slices stay live
+    return vmem_bytes([((W, bc), 4), ((bt, W), 4), ((8, bt), 4),
+                       ((1, bc), 4)], scratch=[((bt, 128 * W), 4)])
+
+
+def _sign_rows(signs: jax.Array) -> jax.Array:
+    """(T,) signs → (8, T) float32: row 0 the signs, rows 1–7 zero."""
+    return jnp.pad(signs.astype(jnp.float32)[None, :], ((0, 7), (0, 0)))
+
+
+def _signed_sum(s_ref, ok) -> jax.Array:
+    """Σ_j sign[j]·ok[j, c] over the (BT, BC) match tile → (1, BC) int32."""
+    signed = jnp.dot(s_ref[...], ok.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)    # (8, BC)
+    return signed[0:1].astype(jnp.int32)
+
+
+def _delta_count_kernel(ct_ref, t_ref, s_ref, o_ref, *, n_words: int):
     ti = pl.program_id(1)
 
     @pl.when(ti == 0)
@@ -56,12 +90,11 @@ def _delta_count_kernel(c_ref, t_ref, s_ref, o_ref, *, n_words: int):
 
     ok = None
     for w in range(n_words):  # static unroll, W is tiny
-        cw = c_ref[:, w][:, None]          # (BC, 1)
-        tw = t_ref[:, w][None, :]          # (1, BT)
-        eq = (cw & tw) == cw               # (BC, BT)
+        cw = ct_ref[w:w + 1, :]            # (1, BC)
+        tw = t_ref[:, w:w + 1]             # (BT, 1)
+        eq = (cw & tw) == cw               # (BT, BC)
         ok = eq if ok is None else (ok & eq)
-    signed = jnp.where(ok, s_ref[...][None, :], jnp.int32(0))
-    o_ref[...] += signed.sum(axis=1).astype(jnp.int32)
+    o_ref[...] += _signed_sum(s_ref, ok)
 
 
 @functools.partial(jax.jit, static_argnames=("bc", "bt", "interpret"))
@@ -81,20 +114,21 @@ def delta_count_pallas(cands: jax.Array, txns: jax.Array, signs: jax.Array,
     T, Wt = txns.shape
     assert W == Wt, (W, Wt)
     assert C % bc == 0 and T % bt == 0, (C, bc, T, bt)
-    grid = (C // bc, T // bt)
-    return pl.pallas_call(
+    check_vmem("delta_count_pallas", delta_count_vmem(W, bc, bt))
+    out = pl.pallas_call(
         functools.partial(_delta_count_kernel, n_words=W),
-        grid=grid,
+        grid=(C // bc, T // bt),
         in_specs=[
-            pl.BlockSpec((bc, W), lambda ci, ti: (ci, 0)),
+            pl.BlockSpec((W, bc), lambda ci, ti: (0, ci)),
             pl.BlockSpec((bt, W), lambda ci, ti: (ti, 0)),
-            pl.BlockSpec((bt,), lambda ci, ti: (ti,)),
+            pl.BlockSpec((8, bt), lambda ci, ti: (0, ti)),
         ],
-        out_specs=pl.BlockSpec((bc,), lambda ci, ti: (ci,)),
-        out_shape=jax.ShapeDtypeStruct((C,), jnp.int32),
+        out_specs=pl.BlockSpec((1, bc), lambda ci, ti: (0, ci)),
+        out_shape=jax.ShapeDtypeStruct((1, C), jnp.int32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(cands.astype(jnp.uint32), txns.astype(jnp.uint32),
-      signs.astype(jnp.int32))
+    )(cands.astype(jnp.uint32).T, txns.astype(jnp.uint32), _sign_rows(signs))
+    return out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
@@ -169,18 +203,16 @@ def delta_count_matmul(cands: jax.Array, txns: jax.Array, signs: jax.Array,
     return acc
 
 
-def _delta_count_matmul_kernel(c_ref, w_ref, t_ref, s_ref, o_ref):
+def _delta_count_matmul_kernel(cb_ref, w_ref, tb_ref, s_ref, o_ref):
     ti = pl.program_id(1)
 
     @pl.when(ti == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    ov = jax.lax.dot_general(c_ref[...], t_ref[...], _DOT_LAST,
-                             preferred_element_type=jnp.int32)   # (BC, BT)
-    signed = jnp.where(ov == w_ref[...][:, None], s_ref[...][None, :],
-                       jnp.int32(0))
-    o_ref[...] += signed.sum(axis=1).astype(jnp.int32)
+    ov = jnp.dot(tb_ref[...], cb_ref[...],
+                 preferred_element_type=jnp.int32)           # (BT, BC)
+    o_ref[...] += _signed_sum(s_ref, ov == w_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("bc", "bt", "interpret"))
@@ -192,30 +224,33 @@ def delta_count_matmul_pallas(cands: jax.Array, txns: jax.Array,
 
     Same pre-padding contract as :func:`delta_count_pallas`.
     """
-    from repro.core.bitset import jpopcount_rows, junpack_bits
+    from repro.core.bitset import jpopcount_rows, junpack_bits_lanes
     C, W = cands.shape
     T, Wt = txns.shape
     assert W == Wt, (W, Wt)
     assert C % bc == 0 and T % bt == 0, (C, bc, T, bt)
+    check_vmem("delta_count_matmul_pallas",
+               delta_count_vmem(W, bc, bt, matmul=True))
     cands = cands.astype(jnp.uint32)
-    cb = junpack_bits(cands)
-    tb = junpack_bits(txns.astype(jnp.uint32))
-    widths = jpopcount_rows(cands)
-    B = cb.shape[1]
-    grid = (C // bc, T // bt)
-    return pl.pallas_call(
+    cb = junpack_bits_lanes(cands).T                  # (B, C) int8
+    tb = junpack_bits_lanes(txns.astype(jnp.uint32))  # (T, B) int8
+    widths = jpopcount_rows(cands)[None, :]           # (1, C) int32
+    B = cb.shape[0]
+    out = pl.pallas_call(
         _delta_count_matmul_kernel,
-        grid=grid,
+        grid=(C // bc, T // bt),
         in_specs=[
-            pl.BlockSpec((bc, B), lambda ci, ti: (ci, 0)),
-            pl.BlockSpec((bc,), lambda ci, ti: (ci,)),
+            pl.BlockSpec((B, bc), lambda ci, ti: (0, ci)),
+            pl.BlockSpec((1, bc), lambda ci, ti: (0, ci)),
             pl.BlockSpec((bt, B), lambda ci, ti: (ti, 0)),
-            pl.BlockSpec((bt,), lambda ci, ti: (ti,)),
+            pl.BlockSpec((8, bt), lambda ci, ti: (0, ti)),
         ],
-        out_specs=pl.BlockSpec((bc,), lambda ci, ti: (ci,)),
-        out_shape=jax.ShapeDtypeStruct((C,), jnp.int32),
+        out_specs=pl.BlockSpec((1, bc), lambda ci, ti: (0, ci)),
+        out_shape=jax.ShapeDtypeStruct((1, C), jnp.int32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(cb, widths, tb, signs.astype(jnp.int32))
+    )(cb, widths, tb, _sign_rows(signs))
+    return out[0]
 
 
 def build_slab(added: np.ndarray, evicted: np.ndarray,
@@ -252,7 +287,8 @@ def delta_count(cands, added, evicted, impl: str = "auto",
       evicted: (E, W) uint32 transactions leaving the window.
       impl:    one of ``DELTA_IMPLS`` ("auto": the autotuned cross-family
                plan winner when autotune is on, else pallas on TPU / jnp
-               elsewhere; "*pallas" off-TPU degrades to interpret).
+               elsewhere; plain "*pallas" names raise off the TPU — the
+               "*_interpret" names run the kernels in the interpreter).
 
     Returns: (C,) int32 — add to the tracked int64 counts.
     """
@@ -272,7 +308,8 @@ def delta_count(cands, added, evicted, impl: str = "auto",
         if plan is not None:
             impl = plan["impl"]
         else:
-            impl = "pallas" if backend == "tpu" else "jnp"
+            impl = ("pallas" if backend == "tpu"
+                    and defaults_fit("delta_pallas", W) else "jnp")
     if impl in ("jnp", "matmul"):
         key = f"delta_{impl}"
         blocks = (tuned_blocks(key, C=C, T=T, W=W) if autotune
@@ -283,7 +320,7 @@ def delta_count(cands, added, evicted, impl: str = "auto",
                  jnp.asarray(signs), block=block)
         return np.asarray(out)
     matmul = impl.startswith("matmul")
-    interpret = impl.endswith("_interpret") or backend != "tpu"
+    interpret = pallas_interpret(impl)
     base = "delta_matmul_pallas" if matmul else "delta_pallas"
     impl_key = f"{base}_interpret" if interpret else base
     blocks = (tuned_blocks(impl_key, C=C, T=T, W=W) if autotune

@@ -1,13 +1,17 @@
 """Jit'd public wrappers around the Pallas kernels, with shape padding and a
-memory-safe blocked-jnp fallback used on non-TPU backends.
+memory-safe blocked-jnp path for non-TPU backends.
 
 ``support_count(cands, txns, impl=...)``
-  impl="pallas"  — the Pallas kernel (interpret=True automatically off-TPU).
+  impl="pallas"  — the Pallas kernel, compiled for the TPU (raises elsewhere).
+  impl="pallas_interpret" — the same kernel in the Pallas interpreter (any
+                   backend; the CPU tests' way to run it).
   impl="jnp"     — blocked pure-jnp path (XLA-vectorized; default on CPU).
   impl="matmul"  — blocked bit-plane int8 dot_general form (DESIGN.md §10;
                    the tensor-core-native formulation, default on GPU).
-  impl="matmul_pallas" — the matmul form as a Pallas MXU kernel.
-  impl="auto"    — pallas on TPU, matmul on GPU, else jnp.
+  impl="matmul_pallas" / "matmul_pallas_interpret" — the matmul form as a
+                   Pallas MXU kernel.
+  impl="auto"    — pallas on TPU (jnp where its blocks overflow VMEM),
+                   matmul on GPU, else jnp.
 """
 
 from __future__ import annotations
@@ -19,11 +23,31 @@ import jax.numpy as jnp
 import numpy as np
 
 from .support_count import (support_count_matmul, support_count_matmul_pallas,
-                            support_count_pallas, DEFAULT_BC, DEFAULT_BT)
+                            support_count_pallas, support_count_vmem,
+                            DEFAULT_BC, DEFAULT_BT)
+from .tpu_params import VMEM_BUDGET_BYTES
 
 
 def _backend() -> str:
     return jax.default_backend()
+
+
+def pallas_interpret(impl: str) -> bool:
+    """The ``interpret`` flag a Pallas impl name asks for.
+
+    ``*_interpret`` names run the kernel in the Pallas interpreter on any
+    backend; the plain names compile it for the TPU, and raise on any other
+    backend rather than quietly switching to the interpreter.
+    """
+    if impl.endswith("_interpret"):
+        return True
+    backend = _backend()
+    if backend != "tpu":
+        raise ValueError(
+            f"impl {impl!r} compiles a Pallas kernel for the TPU, but the "
+            f"backend is {backend!r}; ask for {impl + '_interpret'!r} to run "
+            f"it in the Pallas interpreter")
+    return False
 
 
 def _pad_rows(x: jax.Array, mult: int) -> jax.Array:
@@ -71,7 +95,8 @@ def support_count(cands, txns, impl: str = "auto",
     Args:
       cands: (C, W) uint32 candidate bitmasks (any array-like).
       txns:  (T, W) uint32 transaction bitmasks.
-      impl:  "auto" | "pallas" | "jnp".
+      impl:  "auto" | "jnp" | "matmul" | "pallas" | "matmul_pallas" (and
+             the ``*_interpret`` twins of the Pallas names).
 
     Returns:
       (C,) int32 support counts.
@@ -88,13 +113,16 @@ def support_count(cands, txns, impl: str = "auto",
     if impl == "auto":
         backend = _backend()
         impl = {"tpu": "pallas", "gpu": "matmul"}.get(backend, "jnp")
+        if impl == "pallas" and (support_count_vmem(cands.shape[1], bc, bt)
+                                 > VMEM_BUDGET_BYTES):
+            impl = "jnp"
     if impl == "jnp":
         return _support_count_jnp(cands, txns)
     if impl == "matmul":
         return support_count_matmul(cands, txns)
     if impl in ("pallas", "matmul_pallas", "pallas_interpret",
                 "matmul_pallas_interpret"):
-        interpret = impl.endswith("_interpret") or _backend() != "tpu"
+        interpret = pallas_interpret(impl)
         n_pad = (-txns.shape[0]) % bt
         cp = _pad_rows(cands, bc)
         tp = _pad_rows(txns, bt)

@@ -14,10 +14,12 @@ ready for a device-side ``lax.top_k`` per query.  The consequent-containment
 filtering costs one extra AND/compare per word instead of a second pass over
 the (Q, R) matrix.
 
-Tiling mirrors ``support_count.py``: rules tiled ``(BR, W)`` and baskets
-``(BQ, W)`` into VMEM, one ``(BQ, BR)`` float32 output tile per grid step, the
-word loop statically unrolled (W is tiny).  No accumulation across grid steps
-— every tile is written exactly once.  The blocked-jnp twin
+Tiling mirrors ``support_count.py``: rule words are laid out transposed,
+``(W, BR)`` blocks with rules on the 128-wide lane axis, the ``(1, BR)``
+scores lane-dense beside them, and baskets ``(BQ, W)`` on sublanes; one
+``(BQ, BR)`` float32 output tile per grid step, the word loop statically
+unrolled (W is tiny).  No accumulation across grid steps — every tile is
+written exactly once.  The blocked-jnp twin
 (:func:`rule_scores_jnp`, the CPU production path and bit-exactness oracle)
 scans basket chunks with the same select, so both paths produce identical
 float32 bits.  Block sizes are autotuned via ``kernels/autotune.py`` (§5)
@@ -32,9 +34,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tpu_params import COMPILER_PARAMS, check_vmem, vmem_bytes
+
 DEFAULT_BQ = 256       # baskets per tile (sublane dim)
 DEFAULT_BR = 512       # rules per tile (lane dim)
 DEFAULT_Q_BLOCK = 1024  # basket chunk of the jnp scan
+
+
+def rule_scores_vmem(W: int, bq: int, br: int, matmul: bool = False) -> int:
+    """VMEM bytes of :func:`rule_scores_pallas` (with ``matmul``, of
+    :func:`rule_scores_matmul_pallas`) at W words and (bq, br) blocks."""
+    out = ((bq, br), 4)
+    if matmul:
+        B = -(-W // 4) * 128                 # bit planes, padded to 128 lanes
+        # plus a copy of each int8 operand block that Mosaic makes for the
+        # dots at most widths
+        return vmem_bytes([((B, br), 1), ((1, br), 4), ((B, br), 1),
+                           ((1, br), 4), ((1, br), 4), ((bq, B), 1), out],
+                          scratch=[((B, br), 1), ((B, br), 1), ((bq, B), 1)])
+    # the unrolled word loop's lane-padded (bq, 1) column slices stay live
+    return vmem_bytes([((W, br), 4), ((W, br), 4), ((1, br), 4),
+                       ((bq, W), 4), out], scratch=[((bq, 128 * W), 4)])
 
 
 def _rule_scores_kernel(a_ref, c_ref, s_ref, b_ref, o_ref, *, n_words: int,
@@ -42,17 +62,17 @@ def _rule_scores_kernel(a_ref, c_ref, s_ref, b_ref, o_ref, *, n_words: int,
     ok = None
     bad = None
     for w in range(n_words):  # static unroll, W is tiny
-        aw = a_ref[:, w][None, :]          # (1, BR)
-        bw = b_ref[:, w][:, None]          # (BQ, 1)
+        aw = a_ref[w:w + 1, :]             # (1, BR)
+        bw = b_ref[:, w:w + 1]             # (BQ, 1)
         m = (aw & bw) == aw                # (BQ, BR) antecedent ⊆ basket
         ok = m if ok is None else (ok & m)
         if exclude_contained:
-            cw = c_ref[:, w][None, :]
+            cw = c_ref[w:w + 1, :]
             mc = (cw & bw) == cw           # consequent ⊆ basket — nothing new
             bad = mc if bad is None else (bad & mc)
     if exclude_contained:
         ok = ok & jnp.logical_not(bad)
-    o_ref[...] = jnp.where(ok, s_ref[...][None, :], -jnp.inf)
+    o_ref[...] = jnp.where(ok, s_ref[...], -jnp.inf)
 
 
 @functools.partial(jax.jit,
@@ -82,6 +102,7 @@ def rule_scores_pallas(antes: jax.Array, cons: jax.Array, scores: jax.Array,
     R, W = antes.shape
     Q, Wb = baskets.shape
     assert W == Wb, (W, Wb)
+    check_vmem("rule_scores_pallas", rule_scores_vmem(W, bq, br))
     pad_r = (-R) % br
     if pad_r:
         zrow = jnp.zeros((pad_r, W), antes.dtype)
@@ -94,22 +115,22 @@ def rule_scores_pallas(antes: jax.Array, cons: jax.Array, scores: jax.Array,
         baskets = jnp.concatenate(
             [baskets, jnp.zeros((pad_q, W), baskets.dtype)], axis=0)
     Rp, Qp = antes.shape[0], baskets.shape[0]
-    grid = (Qp // bq, Rp // br)
     out = pl.pallas_call(
         functools.partial(_rule_scores_kernel, n_words=W,
                           exclude_contained=exclude_contained),
-        grid=grid,
+        grid=(Qp // bq, Rp // br),
         in_specs=[
-            pl.BlockSpec((br, W), lambda qi, ri: (ri, 0)),
-            pl.BlockSpec((br, W), lambda qi, ri: (ri, 0)),
-            pl.BlockSpec((br,), lambda qi, ri: (ri,)),
+            pl.BlockSpec((W, br), lambda qi, ri: (0, ri)),
+            pl.BlockSpec((W, br), lambda qi, ri: (0, ri)),
+            pl.BlockSpec((1, br), lambda qi, ri: (0, ri)),
             pl.BlockSpec((bq, W), lambda qi, ri: (qi, 0)),
         ],
         out_specs=pl.BlockSpec((bq, br), lambda qi, ri: (qi, ri)),
         out_shape=jax.ShapeDtypeStruct((Qp, Rp), jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(antes.astype(jnp.uint32), cons.astype(jnp.uint32),
-      scores.astype(jnp.float32), baskets.astype(jnp.uint32))
+    )(antes.astype(jnp.uint32).T, cons.astype(jnp.uint32).T,
+      scores.astype(jnp.float32)[None, :], baskets.astype(jnp.uint32))
     return out[:Q, :R]
 
 
@@ -200,14 +221,14 @@ def rule_scores_matmul(antes: jax.Array, cons: jax.Array, scores: jax.Array,
 
 def _rule_scores_matmul_kernel(a_ref, aw_ref, c_ref, cw_ref, s_ref, b_ref,
                                o_ref, *, exclude_contained: bool):
-    ov = jax.lax.dot_general(b_ref[...], a_ref[...], _DOT_LAST,
-                             preferred_element_type=jnp.int32)   # (BQ, BR)
-    ok = ov == aw_ref[...][None, :]
+    ov = jnp.dot(b_ref[...], a_ref[...],
+                 preferred_element_type=jnp.int32)           # (BQ, BR)
+    ok = ov == aw_ref[...]
     if exclude_contained:
-        ovc = jax.lax.dot_general(b_ref[...], c_ref[...], _DOT_LAST,
-                                  preferred_element_type=jnp.int32)
-        ok &= ovc != cw_ref[...][None, :]
-    o_ref[...] = jnp.where(ok, s_ref[...][None, :], -jnp.inf)
+        ovc = jnp.dot(b_ref[...], c_ref[...],
+                      preferred_element_type=jnp.int32)
+        ok &= ovc != cw_ref[...]
+    o_ref[...] = jnp.where(ok, s_ref[...], -jnp.inf)
 
 
 @functools.partial(jax.jit,
@@ -225,10 +246,12 @@ def rule_scores_matmul_pallas(antes: jax.Array, cons: jax.Array,
     scores and empty consequents (never novel under ``exclude_contained``),
     pad baskets are sliced off before return.
     """
-    from repro.core.bitset import jpopcount_rows, junpack_bits
+    from repro.core.bitset import jpopcount_rows, junpack_bits_lanes
     R, W = antes.shape
     Q, Wb = baskets.shape
     assert W == Wb, (W, Wb)
+    check_vmem("rule_scores_matmul_pallas",
+               rule_scores_vmem(W, bq, br, matmul=True))
     pad_r = (-R) % br
     if pad_r:
         zrow = jnp.zeros((pad_r, W), antes.dtype)
@@ -242,26 +265,27 @@ def rule_scores_matmul_pallas(antes: jax.Array, cons: jax.Array,
             [baskets, jnp.zeros((pad_q, W), baskets.dtype)], axis=0)
     antes = antes.astype(jnp.uint32)
     cons = cons.astype(jnp.uint32)
-    ab, aw = junpack_bits(antes), jpopcount_rows(antes)
-    cb, cw = junpack_bits(cons), jpopcount_rows(cons)
-    bb = junpack_bits(baskets.astype(jnp.uint32))
-    B = ab.shape[1]
+    # rules on lanes: (B, R) int8 planes and (1, R) widths/scores
+    ab, aw = junpack_bits_lanes(antes).T, jpopcount_rows(antes)[None, :]
+    cb, cw = junpack_bits_lanes(cons).T, jpopcount_rows(cons)[None, :]
+    bb = junpack_bits_lanes(baskets.astype(jnp.uint32))     # (Q, B) int8
+    B = ab.shape[0]
     Rp, Qp = antes.shape[0], baskets.shape[0]
-    grid = (Qp // bq, Rp // br)
     out = pl.pallas_call(
         functools.partial(_rule_scores_matmul_kernel,
                           exclude_contained=exclude_contained),
-        grid=grid,
+        grid=(Qp // bq, Rp // br),
         in_specs=[
-            pl.BlockSpec((br, B), lambda qi, ri: (ri, 0)),
-            pl.BlockSpec((br,), lambda qi, ri: (ri,)),
-            pl.BlockSpec((br, B), lambda qi, ri: (ri, 0)),
-            pl.BlockSpec((br,), lambda qi, ri: (ri,)),
-            pl.BlockSpec((br,), lambda qi, ri: (ri,)),
+            pl.BlockSpec((B, br), lambda qi, ri: (0, ri)),
+            pl.BlockSpec((1, br), lambda qi, ri: (0, ri)),
+            pl.BlockSpec((B, br), lambda qi, ri: (0, ri)),
+            pl.BlockSpec((1, br), lambda qi, ri: (0, ri)),
+            pl.BlockSpec((1, br), lambda qi, ri: (0, ri)),
             pl.BlockSpec((bq, B), lambda qi, ri: (qi, 0)),
         ],
         out_specs=pl.BlockSpec((bq, br), lambda qi, ri: (qi, ri)),
         out_shape=jax.ShapeDtypeStruct((Qp, Rp), jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(ab, aw, cb, cw, scores.astype(jnp.float32), bb)
+    )(ab, aw, cb, cw, scores.astype(jnp.float32)[None, :], bb)
     return out[:Q, :R]

@@ -8,23 +8,27 @@ horizontal ``O(N · W)`` — the vertical data layout of Jen et al. (related wor
 
 Kernel design (replaces the gather-heavy jnp scan):
 
-* grid ``(C, Tw // bt, kmax)`` — candidates × transaction-word blocks × item
-  slots, with the item-slot axis **innermost** so a ``(1, bt)`` VMEM scratch
-  accumulator can AND the candidate's item rows for one transaction block
-  before flushing a popcount partial sum into the ``(1,)`` output block
-  (revisit-accumulate over both inner axes).
-* the ``(C, kmax)`` candidate→row index table is **scalar-prefetched**
-  (``PrefetchScalarGridSpec``), so the vertical-DB BlockSpec's index map picks
-  item row ``idx[c, j]`` directly and each row block is DMA'd into VMEM by the
-  pipeline — no gather instruction in the kernel body at all.
+* grid ``(C // bc, Tw // bt)`` — candidate blocks × transaction-word blocks.
+  Each step holds the whole item axis of one word block in VMEM, an
+  ``(I+1, bt)`` tile (catalogs are small next to the transaction axis).
+* the ``(bc, kmax)`` block of the candidate→row index table sits in SMEM, and
+  the kernel gathers each candidate's item rows with dynamic sublane loads,
+  ANDs them, and writes eight candidates at a time into a ``(bc, bt)`` VMEM
+  scratch at an aligned offset.
+* the popcount row sums leave lane-dense: a ``(8, bt) × (bc, bt)ᵀ`` matmul of
+  ones against the popcounts (exact: every value is an integer ≤ 32) gives
+  the ``(1, bc)`` output block, revisited across the word axis.
 * padded candidate slots point at the valid-transaction mask row (the AND
   identity), and transaction-word padding is zeros (contributes 0 popcount),
   so no correction terms are needed.
 
-VMEM per step: one ``(1, bt)`` row block + the ``(1, bt)`` accumulator — tiny;
-``bt`` is lane-dim tiling (multiples of 128, default 512).  The jnp fallback
-(`vertical_count_jnp`, §Perf iteration M-D) remains the production path on
-CPU, where Pallas runs in interpret mode for validation only.
+Every block's last two dimensions are (8, 128) multiples or whole array
+dimensions (the TPU block rule).  The whole item axis in VMEM bounds the
+catalog: :func:`vertical_count_vmem` prices the blocks, and the wrapper
+refuses a shape over the budget (``kernels/tpu_params.py``) — about 2,000
+items at ``bt=512``.  The jnp fallback (`vertical_count_jnp`,
+§Perf iteration M-D) remains the production path on CPU, where Pallas runs in
+interpret mode for validation only.
 """
 
 from __future__ import annotations
@@ -36,31 +40,59 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tpu_params import COMPILER_PARAMS, check_vmem, vmem_bytes
+
 DEFAULT_BLOCK = 2048   # candidate block of the jnp scan fallback
 DEFAULT_BT = 512       # transaction words per block (lane dim, multiple of 128)
+DEFAULT_BC = 256       # candidates per block of the matmul Pallas kernel
+BC = 256               # candidates per block of the popcount Pallas kernel
 
 
-def _vertical_count_kernel(idx_ref, row_ref, o_ref, acc_ref, *, kmax: int):
-    del idx_ref  # consumed by the index maps (scalar prefetch)
-    t = pl.program_id(1)
-    j = pl.program_id(2)
+def vertical_count_vmem(n_rows: int, bt: int, bc: int = BC) -> int:
+    """VMEM bytes of :func:`vertical_count_pallas` over ``n_rows`` (= I+1)
+    item rows at ``bt`` words per block: the whole-item-axis word block, the
+    output block and the ``(bc, bt)`` AND scratch."""
+    return vmem_bytes([((n_rows, bt), 4), ((1, bc), 4)],
+                      scratch=[((bc, bt), 4)])
 
-    @pl.when((t == 0) & (j == 0))
+
+def vertical_count_matmul_vmem(n_rows: int, bc: int, bt: int) -> int:
+    """VMEM bytes of :func:`vertical_count_matmul_pallas` over ``n_rows``
+    (= I+1) item rows at (bc, bt) blocks."""
+    ip = -(-n_rows // 128) * 128             # item axis, padded to 128 lanes
+    # plus a copy of each int8 operand block that Mosaic makes for the dot,
+    # and the (bt, bc) int32 product tile
+    return vmem_bytes([((bt, ip), 1), ((ip, bc), 1), ((1, bc), 4),
+                       ((1, bc), 4)],
+                      scratch=[((bt, ip), 1), ((ip, bc), 1), ((bt, bc), 4)])
+
+
+def _vertical_count_kernel(idx_ref, v_ref, o_ref, acc_ref, *, kmax: int):
+    ti = pl.program_id(1)
+
+    @pl.when(ti == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(j == 0)
-    def _load():
-        acc_ref[...] = row_ref[...]
+    def group(g, carry):                   # eight candidates per aligned store
+        rows = []
+        for r in range(8):
+            c = g * 8 + r
+            row = v_ref[pl.ds(idx_ref[c, 0], 1), :]          # (1, BT)
+            for j in range(1, kmax):
+                row = row & v_ref[pl.ds(idx_ref[c, j], 1), :]
+            rows.append(row)
+        acc_ref[pl.ds(pl.multiple_of(g * 8, 8), 8), :] = jnp.concatenate(
+            rows, axis=0)
+        return carry
 
-    @pl.when(j > 0)
-    def _and():
-        acc_ref[...] &= row_ref[...]
-
-    @pl.when(j == kmax - 1)
-    def _flush():
-        o_ref[...] += jax.lax.population_count(
-            acc_ref[...]).astype(jnp.int32).sum(axis=1)
+    jax.lax.fori_loop(0, acc_ref.shape[0] // 8, group, 0)
+    pc = jax.lax.population_count(acc_ref[...]).astype(
+        jnp.int32).astype(jnp.float32)
+    ones = jnp.ones((8, pc.shape[1]), jnp.float32)
+    sums = jax.lax.dot_general(ones, pc, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)  # (8, BC)
+    o_ref[...] += sums[0:1].astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
@@ -75,31 +107,38 @@ def vertical_count_pallas(vdb: jax.Array, cand_idx: jax.Array,
       cand_idx: (C, kmax) int32 item ids per candidate, padded with I.
       bt:       transaction words per block (clamped to the padded Tw).
 
+    Candidates go ``BC`` to a block (fewer, rounded up to 8, when C is
+    smaller).
+
     Returns: (C,) int32 counts.
     """
     C, kmax = cand_idx.shape
-    _, tw = vdb.shape
-    # Clamp the block to the (128-aligned) word count so tiny DBs don't pad to
-    # a full default block, then zero-pad words up to the block multiple.
+    I1, tw = vdb.shape
+    # Clamp the blocks so tiny inputs don't pad to a full default block, then
+    # pad candidates with sentinel rows and words with zeros to the multiples.
+    bc = min(BC, max(((C + 7) // 8) * 8, 8))
     bt = min(bt, max(((tw + 127) // 128) * 128, 128))
-    pad = (-tw) % bt
-    if pad:
-        vdb = jnp.concatenate(
-            [vdb, jnp.zeros((vdb.shape[0], pad), vdb.dtype)], axis=1)
-    grid = (C, vdb.shape[1] // bt, kmax)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, bt), lambda c, t, j, idx: (idx[c, j], t))],
-        out_specs=pl.BlockSpec((1,), lambda c, t, j, idx: (c,)),
-        scratch_shapes=[pltpu.VMEM((1, bt), jnp.uint32)],
-    )
-    return pl.pallas_call(
+    check_vmem("vertical_count_pallas", vertical_count_vmem(I1, bt, bc))
+    pad_c, pad_t = (-C) % bc, (-tw) % bt
+    cand_idx = jnp.pad(cand_idx.astype(jnp.int32), ((0, pad_c), (0, 0)),
+                       constant_values=I1 - 1)
+    vdb = jnp.pad(vdb.astype(jnp.uint32), ((0, 0), (0, pad_t)))
+    Cp = cand_idx.shape[0]
+    out = pl.pallas_call(
         functools.partial(_vertical_count_kernel, kmax=kmax),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((C,), jnp.int32),
+        grid=(Cp // bc, vdb.shape[1] // bt),
+        in_specs=[
+            pl.BlockSpec((bc, kmax), lambda ci, ti: (ci, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((I1, bt), lambda ci, ti: (0, ti)),
+        ],
+        out_specs=pl.BlockSpec((1, bc), lambda ci, ti: (0, ci)),
+        out_shape=jax.ShapeDtypeStruct((1, Cp), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((bc, bt), jnp.uint32)],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(cand_idx.astype(jnp.int32), vdb.astype(jnp.uint32))
+    )(cand_idx, vdb)
+    return out[0, :C]
 
 
 def vertical_count_jnp(vdb: jax.Array, cand_idx: jax.Array,
@@ -187,61 +226,65 @@ def vertical_count_matmul(vdb: jax.Array, cand_idx: jax.Array,
     return counts.reshape(-1)[:C]
 
 
-def _vertical_matmul_kernel(a_ref, n_ref, v_ref, val_ref, o_ref):
+def _vertical_matmul_kernel(v_ref, a_ref, n_ref, o_ref):
     ti = pl.program_id(1)
 
     @pl.when(ti == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    ov = jax.lax.dot_general(a_ref[...], v_ref[...], (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.int32)  # (BC, BT)
-    match = (ov == n_ref[...][:, None]) & (val_ref[...][None, :] > 0)
-    o_ref[...] += match.sum(axis=1).astype(jnp.int32)
+    ov = jnp.dot(v_ref[...], a_ref[...],
+                 preferred_element_type=jnp.int32)           # (BT, BC)
+    o_ref[...] += (ov == n_ref[...]).astype(jnp.int32).sum(axis=0,
+                                                           keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("bc", "bt", "interpret"))
 def vertical_count_matmul_pallas(vdb: jax.Array, cand_idx: jax.Array,
-                                 bc: int = 256, bt: int = 512,
+                                 bc: int = DEFAULT_BC, bt: int = DEFAULT_BT,
                                  interpret: bool = False) -> jax.Array:
-    """Vertical matmul counting as a Pallas kernel: (BC, I) × (I, BT) int8
-    dots on the MXU, candidates tiled over the grid's first axis and
+    """Vertical matmul counting as a Pallas kernel: (BT, I) × (I, BC) int8
+    matmuls on the MXU, candidates tiled over the grid's first axis and
     transaction columns over the second (the item axis stays whole — catalogs
-    are small next to the transaction axis)."""
+    are small next to the transaction axis).
+
+    The valid-transaction row joins every candidate as one more item, so a
+    column matches only where all the candidate's items *and* the valid bit
+    are set — no separate validity vector.  The item axis is zero-padded to a
+    multiple of 128 (the int8 lane tiling)."""
     from repro.core.bitset import junpack_bits
     vdb = jnp.asarray(vdb)
     cand_idx = jnp.asarray(cand_idx)
     I1, _ = vdb.shape
     n_items = I1 - 1
-    C, kmax = cand_idx.shape
-    vbits = junpack_bits(vdb)
-    item_bits, valid = vbits[:n_items], vbits[n_items]
+    C, _ = cand_idx.shape
+    bc = min(bc, max(((C + 127) // 128) * 128, 128))
+    check_vmem("vertical_count_matmul_pallas",
+               vertical_count_matmul_vmem(I1, bc, bt))
     A, nreal = _vertical_membership(cand_idx, n_items)
+    A = jnp.concatenate([A, jnp.ones((C, 1), A.dtype)], axis=1)   # + valid
+    nreal = nreal + 1
     pad_c = (-C) % bc
-    if pad_c:
-        A = jnp.concatenate([A, jnp.zeros((pad_c, n_items), A.dtype)], axis=0)
-        # a padded candidate row would count every valid txn (empty-set
-        # semantics); poison its width so it never matches instead
-        nreal = jnp.concatenate(
-            [nreal, jnp.full((pad_c,), -1, nreal.dtype)])
-    Tn = item_bits.shape[1]
-    pad_t = (-Tn) % bt
-    if pad_t:
-        item_bits = jnp.concatenate(
-            [item_bits, jnp.zeros((n_items, pad_t), item_bits.dtype)], axis=1)
-        valid = jnp.concatenate([valid, jnp.zeros((pad_t,), valid.dtype)])
-    grid = (A.shape[0] // bc, item_bits.shape[1] // bt)
+    pad_i = (-I1) % 128
+    # a padded candidate row would count every valid txn (empty-set
+    # semantics); poison its width so it never matches instead
+    A = jnp.pad(A, ((0, pad_c), (0, pad_i))).T                 # (Ip, Cp)
+    nreal = jnp.pad(nreal, (0, pad_c), constant_values=-1)[None, :]
+    vbits = junpack_bits(vdb).T                                # (Tn, I+1)
+    pad_t = (-vbits.shape[0]) % bt
+    vbits = jnp.pad(vbits, ((0, pad_t), (0, pad_i)))           # (Tnp, Ip)
+    Ip, Cp = A.shape
     out = pl.pallas_call(
         _vertical_matmul_kernel,
-        grid=grid,
+        grid=(Cp // bc, vbits.shape[0] // bt),
         in_specs=[
-            pl.BlockSpec((bc, n_items), lambda ci, ti: (ci, 0)),
-            pl.BlockSpec((bc,), lambda ci, ti: (ci,)),
-            pl.BlockSpec((n_items, bt), lambda ci, ti: (0, ti)),
-            pl.BlockSpec((bt,), lambda ci, ti: (ti,)),
+            pl.BlockSpec((bt, Ip), lambda ci, ti: (ti, 0)),
+            pl.BlockSpec((Ip, bc), lambda ci, ti: (0, ci)),
+            pl.BlockSpec((1, bc), lambda ci, ti: (0, ci)),
         ],
-        out_specs=pl.BlockSpec((bc,), lambda ci, ti: (ci,)),
-        out_shape=jax.ShapeDtypeStruct((A.shape[0],), jnp.int32),
+        out_specs=pl.BlockSpec((1, bc), lambda ci, ti: (0, ci)),
+        out_shape=jax.ShapeDtypeStruct((1, Cp), jnp.int32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
-    )(A, nreal, item_bits, valid)
-    return out[:C]
+    )(vbits, A, nreal)
+    return out[0, :C]

@@ -12,6 +12,7 @@ import json
 from repro.core import ALGORITHMS, mine
 from repro.core.mapreduce import IMPLS
 from repro.data import dataset_by_name, load_transactions
+from repro.launch.cache import enable_compile_cache
 from repro.launch.cliopts import (add_mesh_args, add_obs_args,
                                   add_policy_args, policy_kwargs_from_args,
                                   runtime_from_args, tracer_from_args,
@@ -37,6 +38,7 @@ def main():
     add_mesh_args(ap)
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     tracer = tracer_from_args(args)
 
     if args.input:

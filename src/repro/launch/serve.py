@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.core.policy import ALGORITHMS
+from repro.launch.cache import enable_compile_cache
 from repro.launch.cliopts import add_policy_args, policy_kwargs_from_args
 from repro.models import build_model
 from repro.serving import ServeEngine
@@ -30,6 +31,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     add_policy_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     model = build_model(args.arch, smoke=args.smoke)
     cfg = model.cfg

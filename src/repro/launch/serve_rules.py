@@ -30,6 +30,7 @@ from repro.core.mapreduce import MapReduceRuntime
 from repro.core.policy import ALGORITHMS
 from repro.costmodel import CostController
 from repro.data import dataset_by_name, load_transactions
+from repro.launch.cache import enable_compile_cache
 from repro.launch.cliopts import (add_obs_args, add_policy_args,
                                   add_serving_args, policy_kwargs_from_args,
                                   tracer_from_args, write_obs_outputs)
@@ -92,6 +93,7 @@ def main():
     add_serving_args(ap)
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     tracer = tracer_from_args(args)
 
     if args.input:

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.core.policy import ALGORITHMS
 from repro.data import dataset_by_name, load_transactions
+from repro.launch.cache import enable_compile_cache
 from repro.launch.cliopts import (add_obs_args, add_policy_args,
                                   policy_kwargs_from_args, tracer_from_args,
                                   write_obs_outputs)
@@ -60,6 +61,7 @@ def main():
     add_policy_args(ap)
     add_obs_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     tracer = tracer_from_args(args)
 
     if args.input:

@@ -17,6 +17,7 @@ from repro.optim import AdamWConfig
 from repro.train import TrainLoop, init_train_state, restore_elastic
 from repro.train.loop import state_shardings
 from repro import sharding
+from repro.launch.cache import enable_compile_cache
 
 
 def main():
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--mesh", action="store_true",
                     help="shard over all local devices")
     args = ap.parse_args()
+    enable_compile_cache()
 
     model = build_model(args.arch, smoke=args.smoke)
     cfg = model.cfg

@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import sharding
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig, ShapeConfig
 
 from . import encdec, transformer
@@ -209,9 +208,9 @@ def sharded_greedy(logits, ctx: ShardCtx):
         cand = jnp.where(m >= gm, a, jnp.int32(2**30))
         return jax.lax.pmin(cand, "model")  # lowest index among ties
 
-    fn = shard_map(local, mesh=mesh,
-                       in_specs=P(None, "model"), out_specs=P(),
-                       check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                           in_specs=P(None, "model"), out_specs=P(),
+                           check_vma=False)
     return fn(logits)
 
 

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 
 import numpy as np
 
-from repro.compat import shard_map
 
 from .layers import dense_init, pdtype
 
@@ -163,7 +162,7 @@ def _moe_apply_ep(p, x, cfg, ctx, data_ax, msize):
         y = jnp.zeros((T, D), xl.dtype).at[ftok_s].add(contrib)
         return y.reshape(Bl, Sl, D), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
         out_specs=(x_spec, P()),

@@ -67,62 +67,87 @@ def count_job_ops(n_candidates: int, n_txns: int, n_words: int = 1,
 
 # -- counting-kernel roofline (DESIGN.md §10) ----------------------------------
 #
-# Per-backend peaks for the achieved-vs-peak fractions BENCH_kernels.json
-# records.  The matmul (bit-plane dot_general) forms are compute-bound and
-# are compared against the int8 matmul peak; the popcount forms stream words
-# and are compared against memory bandwidth.  Figures are nominal
-# device-class numbers (TPU v5e-class MXU, A100-class tensor cores, one
-# desktop-class CPU socket) — the *fraction* is the methodology artifact, so
-# order-of-magnitude peaks are enough to tell "near roofline" from "2% of
-# roofline".
+# Per-chip peaks for the achieved-vs-peak fractions, keyed by the device kind
+# JAX reports (``jax.devices()[0].device_kind``).  The matmul (bit-plane
+# dot) forms are compute-bound and are compared against the int8 matmul peak;
+# the popcount forms stream words and are compared against HBM bandwidth.  A
+# device that is not in the table has no roofline: asking for one raises.
+#
+# Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+# 393 TOP/s int8, 16 GB of HBM at 819 GB/s per chip.
 
 COUNT_PEAKS = {
-    "cpu": {"int8_ops": 2.0e12, "mem_bw": 50e9},
-    "gpu": {"int8_ops": 624e12, "mem_bw": 1550e9},
-    "tpu": {"int8_ops": 394e12, "mem_bw": 819e9},
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "mem_bw": 819e9},
 }
 
 
-def count_kernel_roofline(family: str, *, C: int, T: int, W: int = 1,
-                          kmax: int = 1, seconds: float,
-                          backend: str) -> dict:
-    """Achieved-vs-peak terms for one benched counting-kernel record.
+def count_kernel_roofline(impl: str, *, C: int, T: int, W: int = 1,
+                          kmax: int = 1, n_items: int | None = None,
+                          seconds: float, device_kind: str,
+                          chips: int = 1) -> dict:
+    """Achieved-vs-peak terms for one counting job.
+
+    Each counting form is priced by work its kernel cannot avoid — never
+    more — so the share stays at or under 1 at the true peak:
+
+    * the matmul forms: the ``(C, 32·W) × (32·W, T)`` bit-plane product of
+      ``matmul`` / ``matmul_pallas``, the ``(C, I) × (I, T)`` membership
+      product of ``vertical_matmul`` / ``vertical_matmul_pallas``, 2 int8
+      ops per MAC (the kernels pad the contracted axis further);
+    * ``jnp`` / ``pallas`` (horizontal popcount): the compulsory HBM traffic,
+      every candidate and transaction word read once and one count written;
+    * ``vertical`` (jnp gather): each candidate gathers its ``kmax`` item
+      rows of ``T/32`` words;
+    * ``vertical_pallas``: the kernel streams the whole ``(I+1, T/32)`` item
+      array once per block of ``BC`` candidates, plus the index table; when
+      the word axis fits one default block, the array is read once.
 
     Args:
-      family: "matmul" (bit-plane dot form — any layout), "horizontal"
-              (popcount subset scan) or "vertical" (popcount gather-AND).
-      C/T/W/kmax: the benched shape (T = transaction rows).
-      seconds: measured wall time of one call.
-      backend: "cpu" | "gpu" | "tpu".
+      impl: a counting impl of ``core.mapreduce.IMPLS`` (no ``_interpret``).
+      C/T/W/kmax: the job's shape (T = transaction rows, W = mask words).
+      n_items: the catalog size I (vertical forms only; default 32·W).
+      seconds: measured wall time of the job.
+      device_kind: the chip's ``device_kind``; must be a key of
+              ``COUNT_PEAKS``.
+      chips: devices the job ran on — the whole job's work is compared
+              against ``chips`` times the per-chip peak.
 
     Returns a dict with the achieved rate, the peak it is measured against,
     the ``peak_frac`` ratio, and which resource bounds the form.
     """
-    peaks = COUNT_PEAKS.get(backend, COUNT_PEAKS["cpu"])
+    if device_kind not in COUNT_PEAKS:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"COUNT_PEAKS covers {sorted(COUNT_PEAKS)}")
+    peaks = COUNT_PEAKS[device_kind]
     s = max(float(seconds), 1e-12)
-    if family == "matmul":
-        # (C, W·32) × (W·32, T) int8 dot: 2 ops (mul+add) per MAC
-        macs = float(C) * T * W * 32
-        achieved = 2.0 * macs / s
-        peak = peaks["int8_ops"]
-        bound = "compute"
-        unit = "int8_ops_per_s"
-    elif family == "vertical":
-        # each candidate gathers kmax item rows of T/32 words (4 B each)
-        bytes_touched = 4.0 * C * kmax * max(T / 32.0, 1.0)
-        achieved = bytes_touched / s
-        peak = peaks["mem_bw"]
-        bound = "memory"
-        unit = "bytes_per_s"
-    else:                       # horizontal popcount subset scan
-        # word loads for both operands + the (C, T) match matrix traffic
-        bytes_touched = 4.0 * W * (float(C) + T) + float(C) * T
-        achieved = bytes_touched / s
-        peak = peaks["mem_bw"]
-        bound = "memory"
-        unit = "bytes_per_s"
-    return {"family": family, "bound": bound, "unit": unit,
-            "achieved": float(achieved), "peak": float(peak),
+    tw = -(-int(T) // 32)                    # transaction words
+    items = 32 * W if n_items is None else n_items
+    if "matmul" in impl:
+        # the vertical forms contract over the I items, the horizontal over
+        # the 32·W bit planes of the masks
+        planes = items if impl.startswith("vertical") else 32 * W
+        achieved = 2.0 * float(C) * T * planes / s
+        peak, bound, unit = peaks["int8_ops"], "compute", "int8_ops_per_s"
+    else:
+        if impl == "vertical_pallas":
+            from repro.kernels.vertical_count import BC, DEFAULT_BT
+            # one word block stays resident across the candidate blocks
+            bc = min(BC, -(-C // 8) * 8)
+            fetches = -(-C // bc) if tw > DEFAULT_BT else 1
+            words = fetches * (items + 1) * tw + C * kmax + C
+        elif impl == "vertical":
+            words = C * kmax * tw + C
+        elif impl in ("jnp", "pallas"):
+            words = W * (C + T) + C
+        else:
+            raise ValueError(f"no roofline model for impl {impl!r}")
+        achieved = 4.0 * words / s
+        peak, bound, unit = peaks["mem_bw"], "memory", "bytes_per_s"
+    peak = float(peak) * chips
+    return {"impl": impl, "bound": bound, "unit": unit,
+            "achieved": float(achieved), "peak": peak,
             "peak_frac": float(achieved / peak)}
 
 

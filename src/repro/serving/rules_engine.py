@@ -47,7 +47,9 @@ import numpy as np
 from repro.core.policy import ALGORITHMS, PhaseStats
 from repro.core.rules import RuleSet
 from repro.obs.trace import current_tracer
-from repro.kernels.autotune import DEFAULTS, tuned_blocks, tuned_plan
+from repro.kernels.autotune import (DEFAULTS, defaults_fit, tuned_blocks,
+                                    tuned_plan)
+from repro.kernels.ops import pallas_interpret
 from repro.kernels.rule_match import (rule_scores_jnp, rule_scores_matmul,
                                       rule_scores_matmul_pallas,
                                       rule_scores_pallas)
@@ -107,8 +109,9 @@ class RuleServeEngine:
         ("jnp"/"pallas") or bit-plane matmul ("matmul"/"matmul_pallas",
         DESIGN.md §10) forms; "auto" resolves per dispatch shape to the
         cross-family autotune plan winner when autotune is on (static
-        fallback: pallas on TPU, matmul on GPU, jnp elsewhere); "*pallas"
-        off-TPU degrades to interpret mode, like the counting kernels.
+        fallback: pallas on TPU, matmul on GPU, jnp elsewhere); plain
+        "*pallas" names raise off the TPU, like the counting kernels — the
+        "*_interpret" names run the kernels in the Pallas interpreter.
       algorithm: pass-combining policy fusing queued query batches per
         dispatch (core/policy.py; "spc" = strict per-batch dispatch).
       max_fuse: cap on batches fused into one dispatch.
@@ -225,7 +228,9 @@ class RuleServeEngine:
                 if self.autotune else None)
         if plan is not None and plan["impl"] in RULE_IMPLS:
             return plan["impl"]
-        return {"tpu": "pallas", "gpu": "matmul"}.get(self._backend, "jnp")
+        if self._backend == "tpu" and defaults_fit("rules_pallas", state.W):
+            return "pallas"
+        return "matmul" if self._backend == "gpu" else "jnp"
 
     def _fn(self, state: ArenaState, Qp: int, k: int):
         key = (Qp, k)
@@ -244,8 +249,7 @@ class RuleServeEngine:
                              q_block=qb, exclude_contained=excl)
                 return jax.lax.top_k(s, k)
         else:
-            interpret = (impl.endswith("_interpret")
-                         or self._backend != "tpu")
+            interpret = pallas_interpret(impl)
             base = ("rules_matmul_pallas" if impl.startswith("matmul")
                     else "rules_pallas")
             impl_key = f"{base}_interpret" if interpret else base

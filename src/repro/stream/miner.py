@@ -88,8 +88,8 @@ class StreamMiner:
       impl: delta-counting implementation — popcount ("jnp"/"pallas") or
         bit-plane matmul ("matmul"/"matmul_pallas") forms (DESIGN.md §10);
         "auto" follows the autotuner's cross-family plan winner (static
-        fallback: pallas on TPU, jnp elsewhere); "*pallas" off-TPU degrades
-        to interpret mode.
+        fallback: pallas on TPU, jnp elsewhere); plain "*pallas" names
+        raise off the TPU ("*_interpret" runs them in the interpreter).
       staleness_factor: β-style scale on the re-mine trigger — re-mine when
         ``drift × staleness > staleness_factor × predicted_remine_seconds``.
       controller: a :class:`repro.costmodel.CostController` shared with the

@@ -99,7 +99,8 @@ def test_plan_cached_no_resweep(monkeypatch, tmp_path):
 
 
 def test_plan_survives_family_failures(monkeypatch, tmp_path):
-    """A family whose runner raises is skipped, not fatal."""
+    """A family whose kernel fails to build is a fault, not a quiet drop-out:
+    the sweep raises, and no plan (which would hide the failure) is cached."""
     _fresh(monkeypatch, tmp_path)
     def fake_runner(impl, C, T, W, kmax, **kw):
         return lambda cfg, impl=impl: impl
@@ -109,8 +110,11 @@ def test_plan_survives_family_failures(monkeypatch, tmp_path):
         return 1e-3
     monkeypatch.setattr(at, "_candidate_runner", fake_runner)
     monkeypatch.setattr(at, "time_once", flaky)
-    plan = at.tuned_plan("delta", C=64, T=512, W=1, backend="cpu")
-    assert plan["family"] == "delta_matmul"
+    with pytest.raises(RuntimeError, match="no lowering"):
+        at.tuned_plan("delta", C=64, T=512, W=1, backend="cpu")
+    assert not at._memory_cache
+    assert not (tmp_path / "at.json").exists() or not any(
+        "/plan/" in k for k in json.load(open(tmp_path / "at.json")))
 
 
 def test_runtime_auto_impl_follows_plan(monkeypatch, tmp_path):
@@ -126,6 +130,50 @@ def test_runtime_auto_impl_follows_plan(monkeypatch, tmp_path):
     masks = rng.integers(0, 2**32, (200, 1), dtype=np.uint32)
     rt.scatter_db(masks, n_items=20)
     assert rt.impl == "matmul" and rt.impl in IMPLS
+
+
+RETAIL_W = -(-16_470 // 32)      # FIMI retail: 515 words per mask
+
+
+@pytest.mark.parametrize("family", ["pallas", "delta_pallas", "rules_pallas"])
+def test_defaults_fit_main_path_widths_not_retail(family):
+    assert at.defaults_fit(family, 6)
+    assert not at.defaults_fit(family, RETAIL_W)
+
+
+def test_static_tpu_fallbacks_take_jnp_past_vmem(monkeypatch, tmp_path):
+    """With autotune off on a TPU, each static fallback takes its Pallas
+    kernel only at widths whose default blocks fit VMEM: a catalog as wide
+    as FIMI retail is counted by the jnp forms instead of being refused."""
+    import types
+
+    import jax
+    from repro.core.mapreduce import MapReduceRuntime
+    from repro.kernels.delta_count import delta_count
+    from repro.kernels.ops import _support_count_jnp, support_count
+    from repro.serving.rules_engine import RuleServeEngine
+    _fresh(monkeypatch, tmp_path)
+    monkeypatch.setenv("REPRO_AUTOTUNE", "0")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rng = np.random.default_rng(0)
+    cands = np.zeros((8, RETAIL_W), np.uint32)
+    cands[np.arange(8), rng.integers(0, RETAIL_W, 8)] = 1 << 3
+    txns = rng.integers(0, 2**32, (64, RETAIL_W), dtype=np.uint32)
+
+    assert np.array_equal(np.asarray(support_count(cands, txns)),
+                          np.asarray(_support_count_jnp(cands, txns)))
+    assert np.array_equal(
+        delta_count(cands, txns[:40], txns[40:], autotune=False),
+        delta_count(cands, txns[:40], txns[40:], impl="jnp", autotune=False))
+    rt = MapReduceRuntime(impl="auto", autotune=False)
+    assert rt.impl == "pallas"
+    rt.scatter_db(txns, n_items=32 * RETAIL_W)
+    assert rt.impl == "jnp"
+    engine = types.SimpleNamespace(impl="auto", autotune=False,
+                                   _backend="tpu")
+    pick = lambda W: RuleServeEngine._resolve_impl(
+        engine, types.SimpleNamespace(W=W), 32)
+    assert pick(4) == "pallas" and pick(RETAIL_W) == "jnp"
 
 
 @pytest.mark.slow

@@ -35,7 +35,7 @@ def test_pallas_matches_ref_shapes(C, T, W):
     cands = rng.integers(0, 2**32, (C, W), dtype=np.uint32)
     txns = rng.integers(0, 2**32, (T, W), dtype=np.uint32)
     ref = np.asarray(support_count_ref(jnp.asarray(cands), jnp.asarray(txns)))
-    pal = np.asarray(support_count(cands, txns, impl="pallas"))
+    pal = np.asarray(support_count(cands, txns, impl="pallas_interpret"))
     jn = np.asarray(support_count(cands, txns, impl="jnp"))
     np.testing.assert_array_equal(pal, ref)
     np.testing.assert_array_equal(jn, ref)
@@ -62,7 +62,7 @@ def test_support_count_is_subset_count(cand_sets, txn_sets):
     """Property: count == #transactions containing the candidate."""
     cands = pack_itemsets(cand_sets, 61)
     txns = pack_itemsets(txn_sets, 61)
-    got = np.asarray(support_count(cands, txns, impl="pallas"))
+    got = np.asarray(support_count(cands, txns, impl="pallas_interpret"))
     for i, cs in enumerate(cand_sets):
         want = sum(1 for t in txn_sets if set(cs) <= set(t))
         assert got[i] == want
@@ -73,7 +73,7 @@ def test_zero_padding_safety():
     cands = pack_itemsets([[0], []], 32)
     txns = np.concatenate([pack_itemsets([[0], [1]], 32),
                            np.zeros((5, 1), np.uint32)])
-    for impl in ("pallas", "matmul", "matmul_pallas"):
+    for impl in ("pallas_interpret", "matmul", "matmul_pallas_interpret"):
         got = np.asarray(support_count(cands, txns, impl=impl))
         assert got[0] == 1      # [0] ⊆ only the first txn
         assert got[1] == 7      # empty set ⊆ everything incl. zero rows
@@ -97,7 +97,8 @@ def test_bitplane_pack_roundtrip():
 
 
 @pytest.mark.parametrize("C,T,W", [(1, 1, 1), (17, 33, 2), (300, 700, 8)])
-@pytest.mark.parametrize("impl", ["matmul", "matmul_pallas"])
+@pytest.mark.parametrize("impl", [
+    "matmul", pytest.param("matmul_pallas_interpret", id="matmul_pallas")])
 def test_matmul_impls_match_ref(C, T, W, impl):
     rng = np.random.default_rng(C + T + W)
     cands = rng.integers(0, 2**32, (C, W), dtype=np.uint32)
@@ -213,8 +214,80 @@ def test_matmul_support_count_is_subset_count(cand_sets, txn_sets):
     cands = pack_itemsets(cand_sets, 61)
     txns = pack_itemsets(txn_sets, 61)
     got = np.asarray(support_count(cands, txns, impl="matmul"))
-    gotp = np.asarray(support_count(cands, txns, impl="matmul_pallas"))
+    gotp = np.asarray(support_count(cands, txns,
+                                    impl="matmul_pallas_interpret"))
     for i, cs in enumerate(cand_sets):
         want = sum(1 for t in txn_sets if set(cs) <= set(t))
         assert got[i] == want
         assert gotp[i] == want
+
+
+# ---------------------------------------------------------------------------
+# no fallback that hides the device: plain Pallas names compile for the TPU
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("impl", ["pallas", "matmul_pallas"])
+def test_compiled_pallas_off_tpu_raises(impl):
+    cands = pack_itemsets([[0], [1, 2]], 32)
+    txns = pack_itemsets([[0, 1, 2], [1]], 32)
+    with pytest.raises(ValueError, match="_interpret"):
+        support_count(cands, txns, impl=impl)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "matmul_pallas"])
+def test_compiled_pallas_delta_and_rules_off_tpu_raise(impl):
+    from repro.core import generate_ruleset, mine
+    from repro.kernels.delta_count import delta_count
+    from repro.serving.rules_engine import RuleServeEngine
+    cands = pack_itemsets([[0], [1, 2]], 32)
+    added = pack_itemsets([[0, 1, 2]], 32)
+    with pytest.raises(ValueError, match="_interpret"):
+        delta_count(cands, added, added[:0], impl=impl, autotune=False)
+    res = mine([[0, 1], [0, 1], [0, 1, 2]], n_items=3, min_sup=0.5)
+    rules = generate_ruleset(res, 0.5)
+    assert len(rules)
+    engine = RuleServeEngine(rules, impl=impl, autotune=False)
+    with pytest.raises(ValueError, match="_interpret"):
+        engine.query([[0]])
+
+
+def test_count_kernel_roofline_needs_published_peaks():
+    from repro.roofline import COUNT_PEAKS, count_kernel_roofline
+    with pytest.raises(ValueError, match="no published peaks"):
+        count_kernel_roofline("matmul", C=256, T=1024, W=6, seconds=1e-3,
+                              device_kind="cpu")
+    v5e = COUNT_PEAKS["TPU v5 lite"]
+    assert (v5e["bf16_flops"], v5e["int8_ops"], v5e["mem_bw"]) == \
+        (197e12, 393e12, 819e9)
+    r = count_kernel_roofline("matmul", C=256, T=1024, W=6, seconds=1e-3,
+                              device_kind="TPU v5 lite")
+    assert r["bound"] == "compute" and r["peak"] == 393e12
+    assert r["peak_frac"] == pytest.approx(2 * 256 * 1024 * 6 * 32 / 1e-3
+                                           / 393e12)
+
+
+def test_count_kernel_roofline_prices_each_impl():
+    """Each impl is priced by the work its kernel cannot avoid; a job on n
+    chips is compared against n chips' peak."""
+    from repro.roofline import count_kernel_roofline
+    kw = dict(C=4096, T=200_000, W=6, kmax=3, n_items=192, seconds=1.0,
+              device_kind="TPU v5 lite")
+    tw = 6250
+    vp = count_kernel_roofline("vertical_pallas", **kw)
+    # 16 candidate blocks of 256, each streaming all 193 item rows
+    assert vp["achieved"] == 4.0 * (16 * 193 * tw + 4096 * 3 + 4096)
+    # 8,124 rows are 254 words: one word block, read once
+    small = count_kernel_roofline("vertical_pallas", **{**kw, "T": 8124})
+    assert small["achieved"] == 4.0 * (193 * 254 + 4096 * 3 + 4096)
+    assert count_kernel_roofline("vertical", **kw)["achieved"] == \
+        4.0 * (4096 * 3 * tw + 4096)
+    for impl in ("jnp", "pallas"):
+        assert count_kernel_roofline(impl, **kw)["achieved"] == \
+            4.0 * (6 * (4096 + 200_000) + 4096)
+    assert count_kernel_roofline("vertical_matmul_pallas", **kw)[
+        "achieved"] == 2.0 * 4096 * 200_000 * 192
+    four = count_kernel_roofline("vertical_pallas", chips=4, **kw)
+    assert four["peak"] == 4 * vp["peak"]
+    assert four["peak_frac"] == pytest.approx(vp["peak_frac"] / 4)
+    with pytest.raises(ValueError, match="no roofline model"):
+        count_kernel_roofline("vertical_interpret", **kw)
