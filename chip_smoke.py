@@ -13,8 +13,9 @@ Phases on one chip, through the entry points behind ``launch/mine.py``,
            20), min_sup 0.25, optimized_vfpc on a 1x1 mesh with elastic=False:
            impl="auto" once, then every counting form in ``IMPLS`` that is not
            ``*_interpret``.  Itemsets must equal ``sequential_apriori``.
-           Each mine is traced: every count job's span must carry a
-           roofline bound and a share of the chip's peak in (0, 1].
+           Each mine is traced: every count job's span must hold its
+           host steps (``mine.count.prep``, ``mine.count.wait``) and carry
+           ``count_seconds``.
   serve    mushroom at scale 1.0, min_sup 0.35, min_conf 0.7; 256 seeded
            queries in batches of 16 through ``RuleServeEngine`` with jnp,
            pallas and matmul_pallas.  Recommendations must be identical.
@@ -24,8 +25,8 @@ Phases on one chip, through the entry points behind ``launch/mine.py``,
 
 With ``--chips 4`` it mines c20d200k on a 4x1 and a 2x2 ``make_mining_mesh``
 (candidates sharded over ``cand``) and on one chip, and compares the three
-sets of itemsets, each mine traced and its roofline shares checked against
-the whole mesh's peak; it runs no other phase.
+sets of itemsets, each mine traced and its count spans checked as above; it
+runs no other phase.
 
 Every step prints one line: phase, impl, wall seconds, XLA compiles, compile
 seconds, persistent-cache hits and whether the answer matched.  The last line
@@ -211,27 +212,33 @@ def load_c20d200k(smoke: Smoke):
 
 
 def _mine_on(mesh, db, n_items, impl=None, cand_axis=None, autotune=True):
-    """One traced mine; returns the runtime, the result and the roofline
-    check of its count jobs (:func:`_roofline_ok`)."""
+    """One traced mine; returns the runtime, the result and the span
+    check of its count jobs (:func:`_count_spans_ok`)."""
     rt = MapReduceRuntime(mesh=mesh, impl=impl, cand_axis=cand_axis,
                           autotune=autotune)
     tracer = Tracer()
     with use_tracer(tracer):
         res = mine(db_masks=db, n_items=n_items, min_sup=MINE_SUP,
                    algorithm="optimized_vfpc", runtime=rt, elastic=False)
-    return rt, res, _roofline_ok(tracer)
+    return rt, res, _count_spans_ok(tracer)
 
 
-def _roofline_ok(tracer: Tracer) -> tuple[bool, str]:
-    """Every ``mine.count`` span carries a roofline bound and a share of the
-    mesh's peak in (0, 1]."""
+def _count_spans_ok(tracer: Tracer) -> tuple[bool, str]:
+    """Every ``mine.count`` span holds one ``mine.count.prep`` and one
+    ``mine.count.wait`` span and carries ``count_seconds``."""
     spans = [s for s in tracer.spans if s.name == "mine.count"]
-    fracs = [s.attrs.get("roofline_peak_frac") for s in spans]
+
+    def children(c, name):
+        return [s for s in tracer.spans if s.name == name
+                and c.t0 <= s.t0 and s.t1 <= c.t1]
+
     ok = bool(spans) and all(
-        s.attrs.get("roofline_bound") in ("compute", "memory")
-        and f is not None and 0.0 < f <= 1.0 for s, f in zip(spans, fracs))
-    top = max((f for f in fracs if f is not None), default=float("nan"))
-    return ok, f"count_jobs={len(spans)} roofline_max={top:.6f}"
+        len(children(c, "mine.count.prep")) == 1
+        and len(children(c, "mine.count.wait")) == 1
+        and "count_seconds" in c.attrs for c in spans)
+    wait = sum(s.duration for s in tracer.spans
+               if s.name == "mine.count.wait")
+    return ok, f"count_jobs={len(spans)} count_wait_s={wait:.6f}"
 
 
 def _levels_note(itemsets: dict) -> str:
@@ -245,13 +252,13 @@ def mine_phase(smoke: Smoke) -> None:
                         check=False)
 
     def run(impl):
-        rt, res, (roof_ok, roof) = _mine_on(
+        rt, res, (spans_ok, spans_note) = _mine_on(
             make_mining_mesh(1, 1), db, n_items, impl=impl,
             autotune=impl is None)
         got = res.itemsets()
-        return got == oracle and roof_ok, \
+        return got == oracle and spans_ok, \
             (f"chose={rt.impl} " if impl is None else "") \
-            + _levels_note(got) + f" phases={res.n_phases} {roof}"
+            + _levels_note(got) + f" phases={res.n_phases} {spans_note}"
 
     smoke.step("mine", "auto", lambda: run(None))
     for impl in IMPLS:
@@ -311,12 +318,12 @@ def mesh_phase(smoke: Smoke, n_chips: int) -> None:
     found = {}
 
     def run(name, mesh, cand_axis=None):
-        rt, res, (roof_ok, roof) = _mine_on(mesh, db, n_items,
-                                            cand_axis=cand_axis)
+        rt, res, (spans_ok, spans_note) = _mine_on(mesh, db, n_items,
+                                                   cand_axis=cand_axis)
         found[name] = res.itemsets()
-        return (found[name] == found.get("1x1", found[name]) and roof_ok,
+        return (found[name] == found.get("1x1", found[name]) and spans_ok,
                 f"mesh={rt.mesh_split[0]}x{rt.mesh_split[1]} impl={rt.impl} "
-                + _levels_note(found[name]) + f" {roof}")
+                + _levels_note(found[name]) + f" {spans_note}")
 
     smoke.step("mesh", "1x1", lambda: run(
         "1x1", make_mesh((1, 1), ("data", "cand"), jax.devices()[:1])))

@@ -28,7 +28,7 @@ from repro.obs.trace import current_tracer
 
 from .bitset import pack_itemsets, singleton_masks, unpack_itemsets
 from .mapreduce import MapReduceRuntime
-from .phases import PhaseResult, bucket_pad, count_roofline_attrs, run_phase
+from .phases import PhaseResult, bucket_pad, run_phase
 from .policy import ALGORITHMS, MeasuredPolicy, PhaseStats
 
 # speculate on the next phase's join only when the current level kept at least
@@ -52,6 +52,7 @@ class MiningResult:
     retries: int = 0                # failed counting jobs recovered by retry
     repartitions: int = 0           # elastic mesh re-layouts this run (§11)
     overlap_seconds: float = 0.0    # host gen time overlapped with counting jobs
+    bytes_to_device: int = 0        # database and candidate bytes placed
     decisions: list = dataclasses.field(default_factory=list)
     # cost-controller telemetry rows for this run (DESIGN.md §9)
 
@@ -206,6 +207,7 @@ def mine(transactions=None, *, db_masks: np.ndarray | None = None,
                            n_items=n_items, min_sup=min_sup)
     overlap_start = runtime.stats.overlap_seconds
     repartitions_start = runtime.stats.repartitions
+    bytes_to_device_start = runtime.stats.bytes_to_device
     with tracer.span("mine.scatter", n_txns=n_txns, n_words=n_words):
         db_sharded = runtime.scatter_db(db_masks, n_items=n_items)
     # re-pin: an "auto" runtime may have switched impl at scatter time
@@ -271,26 +273,23 @@ def mine(transactions=None, *, db_masks: np.ndarray | None = None,
         job1_span = tracer.span("mine.phase", k_start=1, npass=1)
 
         def _job1():
-            padded = bucket_pad(singles)
             t_c = time.perf_counter()
-            cspan = tracer.span(
-                "mine.count", k_start=1, npass=1, n_candidates=n_items,
-                padded=int(padded.shape[0]), impl=runtime.impl, fused=pipeline)
-            try:
-                fut = runtime.phase_count_async(
-                    db_sharded, padded,
-                    min_count=min_count if pipeline else None, n_valid=n_items)
-                cspan.event("count.dispatch")
+            with tracer.span("mine.count", k_start=1, npass=1,
+                             n_candidates=n_items, impl=runtime.impl,
+                             fused=pipeline) as cspan:
+                with tracer.span("mine.count.prep"):
+                    padded = bucket_pad(singles)
+                    payload = runtime.place_candidates(padded)
+                cspan.set(padded=int(padded.shape[0]))
+                fut = runtime.dispatch_count(
+                    db_sharded, payload,
+                    min_count=min_count if pipeline else None,
+                    n_valid=n_items)
                 if count_hook is not None:
                     count_hook("count_dispatch", 1)
-                res = fut.result()
-            finally:
-                t_el = time.perf_counter() - t_c
-                if tracer.enabled:
-                    cspan.set(count_seconds=t_el, **count_roofline_attrs(
-                        runtime, int(padded.shape[0]), n_txns, n_words,
-                        1, t_el))
-                cspan.close()
+                with tracer.span("mine.count.wait"):
+                    res = fut.result()
+                cspan.set(count_seconds=time.perf_counter() - t_c)
             return res if pipeline else res[:n_items]
 
         if pipeline:
@@ -305,9 +304,10 @@ def mine(transactions=None, *, db_masks: np.ndarray | None = None,
         phases.append(PhaseResult(1, 1, [n_items], 0.0, el, el,
                                   [int(keep.sum())], {1: levels[1]}, True))
         history.append((n_items, int(keep.sum()), el))
-        controller.observe_count(
-            n_items, el,
-            bytes_to_host=runtime.stats.bytes_to_host - bytes0)
+        with tracer.span("mine.calibrate"):
+            controller.observe_count(
+                n_items, el,
+                bytes_to_host=runtime.stats.bytes_to_host - bytes0)
         k_prev = 1
         if checkpoint_dir:
             _save_ckpt(checkpoint_dir, algorithm, min_sup, levels, history, k_prev)
@@ -391,11 +391,12 @@ def mine(transactions=None, *, db_masks: np.ndarray | None = None,
         # calibrate on the phase's full cost (minus the speculative join that
         # belongs to the next phase) — the intercept must capture generation
         # and host-sync overhead too, or fusion looks worthless to the model
-        controller.observe_count(
-            sum(res.candidate_counts),
-            max(res.elapsed_seconds - res.spec_seconds, 0.0),
-            bytes_to_host=runtime.stats.bytes_to_host - bytes0)
-        controller.observe_spec(res.spec_seconds)
+        with tracer.span("mine.calibrate"):
+            controller.observe_count(
+                sum(res.candidate_counts),
+                max(res.elapsed_seconds - res.spec_seconds, 0.0),
+                bytes_to_host=runtime.stats.bytes_to_host - bytes0)
+            controller.observe_spec(res.spec_seconds)
         phases.append(res)
         levels.update(res.levels)
         # policies see the phase's own cost: speculative-join time belongs to
@@ -436,4 +437,5 @@ def mine(transactions=None, *, db_masks: np.ndarray | None = None,
         retries=retries,
         repartitions=runtime.stats.repartitions - repartitions_start,
         overlap_seconds=runtime.stats.overlap_seconds - overlap_start,
+        bytes_to_device=runtime.stats.bytes_to_device - bytes_to_device_start,
         decisions=controller.decision_rows(decisions_mark))

@@ -70,6 +70,7 @@ class RuntimeStats:
     fused_dispatches: int = 0   # jobs that filtered on device
     overlap_seconds: float = 0.0  # host gen time spent while a job was in flight
     bytes_to_host: int = 0      # result bytes actually fetched from device
+    bytes_to_device: int = 0    # database and candidate bytes placed on devices
     repartitions: int = 0       # elastic mesh re-layouts (DESIGN.md §11)
     scatter_seconds: float = 0.0  # host time spent (re-)placing the database
 
@@ -122,7 +123,6 @@ class CountFuture:
         self._with_counts = with_counts
         self._n = n_rows
         self._result = None
-        self.wait_seconds = 0.0   # host time actually blocked in result()
 
     def ready(self) -> bool:
         """Non-blocking completion probe."""
@@ -131,9 +131,7 @@ class CountFuture:
 
     def result(self):
         if self._result is None:
-            t0 = time.perf_counter()
             raw = jax.block_until_ready(self._raw)
-            self.wait_seconds = time.perf_counter() - t0
             stats = self._rt.stats
             if self._fused:
                 packed = np.asarray(raw[0])
@@ -273,14 +271,14 @@ class MapReduceRuntime:
         if self.vertical:
             assert self._n_items is not None, "vertical impl needs n_items"
             per = db_masks.shape[0] // d
-            shards = np.stack([
+            host = np.stack([
                 vertical_pack(db_masks[i * per:(i + 1) * per], self._n_items)
                 for i in range(d)])                      # (d, I+1, Tw)
-            out = jax.device_put(
-                shards, NamedSharding(self.mesh, P("data", None, None)))
+            spec = P("data", None, None)
         else:
-            out = jax.device_put(
-                db_masks, NamedSharding(self.mesh, P("data", None)))
+            host, spec = db_masks, P("data", None)
+        out = jax.device_put(host, NamedSharding(self.mesh, spec))
+        self.stats.bytes_to_device += host.nbytes
         self.stats.scatter_seconds += time.perf_counter() - t0
         return out
 
@@ -402,6 +400,10 @@ class MapReduceRuntime:
             in_specs = (db_spec, cand_spec)
             out_specs = out_spec
 
+        # a stable program name per form and mode, e.g.
+        # jit_mapper_vertical_pallas_plain in a device trace
+        mode = ("fused" if with_counts else "fused_mask") if fused else "plain"
+        mapper.__name__ = mapper.__qualname__ = f"mapper_{impl}_{mode}"
         fn = jax.shard_map(mapper, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
         return jax.jit(fn)
@@ -424,21 +426,14 @@ class MapReduceRuntime:
         idx[rows, np.arange(rows.size) - starts[rows]] = cols
         return idx
 
-    def phase_count_async(self, db_sharded, cands_padded: np.ndarray,
-                          min_count: float | None = None,
-                          with_counts: bool = True,
-                          n_valid: int | None = None) -> CountFuture:
-        """Dispatch one MapReduce job without waiting for it.
+    def place_candidates(self, cands_padded: np.ndarray) -> jax.Array:
+        """Build one job's candidate payload on the host and place it on the
+        mesh: the ``(C, kmax)`` item index for the vertical forms, the
+        ``(C, W)`` masks otherwise.
 
         ``cands_padded`` rows must already be padded to the runtime block
-        multiple (see phases.bucket_pad).  When ``min_count`` is given the job
-        is **fused**: the support filter runs on device and only the packed
-        keep mask (+ filtered counts unless ``with_counts=False``) is
-        transferred when the returned :class:`CountFuture` is consumed —
-        sliced on device to ``n_valid`` rows (the real, pre-padding candidate
-        count), so the bucket-pad tail never crosses to the host.
-        """
-        fused = min_count is not None
+        multiple (see phases.bucket_pad); a candidate-sharded mesh pads them
+        further here."""
         if self.cand_axis is not None:
             # candidate-sharded jobs need rows divisible by the cand shards
             # AND per-shard rows on a 32-row word boundary, so the fused
@@ -450,13 +445,33 @@ class MapReduceRuntime:
                     [cands_padded,
                      np.zeros((pad, cands_padded.shape[1]), np.uint32)])
         if self.vertical:
-            payload = jnp.asarray(self._padded_indices(cands_padded))
+            host = self._padded_indices(cands_padded)
         else:
-            payload = jnp.asarray(cands_padded, dtype=jnp.uint32)
+            host = np.asarray(cands_padded, dtype=np.uint32)
+        spec = P(self.cand_axis, None) if self.cand_axis else P(None, None)
+        payload = jax.device_put(host, NamedSharding(self.mesh, spec))
+        self.stats.bytes_to_device += host.nbytes
+        return payload
+
+    def dispatch_count(self, db_sharded, payload: jax.Array,
+                       min_count: float | None = None,
+                       with_counts: bool = True,
+                       n_valid: int | None = None) -> CountFuture:
+        """Dispatch one MapReduce job over a placed payload
+        (:meth:`place_candidates`) without waiting for it.
+
+        When ``min_count`` is given the job is **fused**: the support filter
+        runs on device and only the packed keep mask (+ filtered counts
+        unless ``with_counts=False``) is transferred when the returned
+        :class:`CountFuture` is consumed — sliced on device to ``n_valid``
+        rows (the real, pre-padding candidate count), so the bucket-pad tail
+        never crosses to the host.
+        """
+        fused = min_count is not None
         if not fused:
             # unfused keeps the legacy full-padded transfer
             n_valid = None
-        n_rows = int(cands_padded.shape[0]) if n_valid is None else int(n_valid)
+        n_rows = int(payload.shape[0]) if n_valid is None else int(n_valid)
         key = (fused, with_counts, n_valid, db_sharded.shape, payload.shape,
                tuple(self.mesh.shape.items()), self.cand_axis, self.impl)
         if key not in self._jitted:
@@ -466,10 +481,6 @@ class MapReduceRuntime:
         if key not in self._shape_cache:
             self._shape_cache.add(key)
             self.stats.compiles += 1
-        payload = jax.device_put(
-            payload,
-            NamedSharding(self.mesh,
-                          P(self.cand_axis, None) if self.cand_axis else P(None, None)))
         args = (db_sharded, payload)
         if fused:
             # integer threshold: counts are ints, so >= ceil(min_count) is
@@ -477,11 +488,21 @@ class MapReduceRuntime:
             args += (jnp.int32(math.ceil(min_count)),)
         out = self._jitted[key](*args)
         self.stats.dispatches += 1
-        self.stats.rows_counted += int(cands_padded.shape[0])
+        self.stats.rows_counted += int(payload.shape[0])
         if fused:
             self.stats.fused_dispatches += 1
         return CountFuture(self, out, fused=fused, with_counts=with_counts,
                            n_rows=n_rows)
+
+    def phase_count_async(self, db_sharded, cands_padded: np.ndarray,
+                          min_count: float | None = None,
+                          with_counts: bool = True,
+                          n_valid: int | None = None) -> CountFuture:
+        """:meth:`place_candidates` then :meth:`dispatch_count`."""
+        return self.dispatch_count(db_sharded,
+                                   self.place_candidates(cands_padded),
+                                   min_count=min_count,
+                                   with_counts=with_counts, n_valid=n_valid)
 
     def phase_count(self, db_sharded, cands_padded: np.ndarray) -> np.ndarray:
         """Synchronous unfused job: host int64 counts for every padded row."""

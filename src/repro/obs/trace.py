@@ -20,6 +20,12 @@ Design points:
   caller-supplied start/end (the open-loop server's virtual arrival clock),
   on their own ``tid`` track; the exporter normalizes timestamps *per track*
   so wall-clock and virtual-time tracks both start at 0.
+* **One clock with the device trace** — every span :meth:`Tracer.span`
+  opens also opens a ``jax.profiler.TraceAnnotation`` of the same name, and
+  closing the span closes it.  Under ``jax.profiler`` the spans then land on
+  the profiler's host plane, on the same clock as the device's operations.
+  ``jax.profiler`` is imported when a :class:`Tracer` is made, so ``obs``
+  imports without JAX; ``add_span``'s virtual-time spans are not mirrored.
 """
 
 from __future__ import annotations
@@ -43,7 +49,8 @@ class Span:
     ``t1`` on ``__exit__``/``close``.
     """
 
-    __slots__ = ("name", "tid", "t0", "t1", "attrs", "_tracer")
+    __slots__ = ("name", "tid", "t0", "t1", "attrs", "_tracer",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, tid: str,
                  t0: float, attrs: dict):
@@ -53,6 +60,7 @@ class Span:
         self.t0 = t0
         self.t1: Optional[float] = None
         self.attrs = attrs
+        self._annotation = None     # the open profiler annotation, if any
 
     @property
     def duration(self) -> float:
@@ -86,22 +94,29 @@ class Tracer:
     enabled = True
 
     def __init__(self, clock=None, pid: int = 0):
+        from jax.profiler import TraceAnnotation
         self.clock = clock if clock is not None else MonotonicClock()
         self.pid = pid
         self.spans: list[Span] = []
         self.events: list[dict] = []
         self._stack: list[Span] = []
+        self._annotate = TraceAnnotation
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, tid: str = "main", **attrs) -> Span:
-        """Open a nested span on the live clock; close via ``with`` or
-        ``.close()``."""
+        """Open a nested span on the live clock, and a profiler annotation
+        of the same name; close both via ``with`` or ``.close()``."""
         s = Span(self, name, tid, self.clock.now(), attrs)
+        s._annotation = self._annotate(name)
+        s._annotation.__enter__()
         self.spans.append(s)
         self._stack.append(s)
         return s
 
     def _close(self, s: Span) -> None:
+        if s._annotation is not None:
+            s._annotation.__exit__(None, None, None)
+            s._annotation = None
         s.t1 = self.clock.now()
         if s in self._stack:            # tolerate out-of-order closes
             self._stack.remove(s)

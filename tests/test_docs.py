@@ -166,6 +166,6 @@ def test_observability_documented():
     design = (ROOT / "DESIGN.md").read_text()
     for surface in ("Tracer", "FakeClock", "MonotonicClock", "NULL_TRACER",
                     "schema_version", "validate_snapshot", "add_span",
-                    "serve.query", "mine.phase", "roofline_peak_frac",
+                    "serve.query", "mine.phase", "mine.count.prep",
                     "decision."):
         assert surface in design, f"DESIGN.md §13 must document {surface}"

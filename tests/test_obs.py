@@ -337,61 +337,92 @@ def _tiny_txns(seed=0, n=60, n_items=10):
             for _ in range(n)]
 
 
+def _within(inner, outer) -> bool:
+    return outer.t0 <= inner.t0 and inner.t1 <= outer.t1
+
+
 def test_traced_mine_span_taxonomy_and_wallclock(fresh_registry):
     from repro.core import mine
     tr = Tracer()
     with use_tracer(tr):
         res = mine(_tiny_txns(), n_items=10, min_sup=0.2)
     names = {s.name for s in tr.spans}
-    assert {"mine.run", "mine.scatter", "mine.phase",
-            "mine.gen", "mine.count"} <= names
+    assert {"mine.run", "mine.scatter", "mine.phase", "mine.gen",
+            "mine.count", "mine.count.prep", "mine.count.wait"} <= names
+    assert all(s.t1 is not None for s in tr.spans)
     (run,) = [s for s in tr.spans if s.name == "mine.run"]
     phases = [s for s in tr.spans if s.name == "mine.phase"]
     assert len(phases) == res.n_phases
-    # the run span and the reported wall-clock are the same boundaries
-    assert run.duration == pytest.approx(res.total_seconds, rel=0.05)
-    # per-level phase spans sum (within tolerance) to the run's wall-clock:
-    # the gap is scatter + controller bookkeeping between phases
-    phase_sum = sum(p.duration for p in phases)
-    assert phase_sum <= run.duration * 1.001
-    assert phase_sum >= 0.5 * run.duration
-    # count spans carry the roofline achieved-vs-peak attributes (§10) on a
-    # chip with published peaks; a CPU run has no device roofline and the
-    # spans carry none rather than a share of made-up peaks
+    # every other span of the mine lies inside the run span
+    assert all(_within(s, run) for s in tr.spans if s is not run)
+    # the run span and the reported wall-clock are the same boundaries, read
+    # a few statements apart on the same clock
+    assert run.duration == pytest.approx(res.total_seconds, abs=0.05)
+    # each count job: one prep, then one wait, both inside it
     counts = [s for s in tr.spans if s.name == "mine.count"]
-    assert counts
+    assert len(counts) == res.dispatches
     for c in counts:
+        kids = sorted((s for s in tr.spans if s.name.startswith("mine.count.")
+                       and _within(s, c)), key=lambda s: s.t0)
+        assert [k.name for k in kids] == ["mine.count.prep",
+                                          "mine.count.wait"]
+        assert kids[0].t1 <= kids[1].t0
         assert "count_seconds" in c.attrs
         assert not any(k.startswith("roofline_") for k in c.attrs)
+    # nothing of the count job's host side falls between gen and count
+    gens = [s for s in tr.spans if s.name == "mine.gen"]
+    assert gens
+    for g in gens:
+        assert g.attrs["prune_seconds"] >= 0.0
+        assert g.attrs["prune_seconds"] <= g.duration
+    assert not tr.events                  # no count.dispatch instants
     # registry mirrored the RuntimeStats increments 1:1
     assert fresh_registry.value("mine.dispatches") == res.dispatches
     assert fresh_registry.value("mine.compiles") == res.compiles
+    assert fresh_registry.value("mine.bytes_to_device") == \
+        res.bytes_to_device > 0
     snap = fresh_registry.snapshot()
     assert snap["gauges"]["mine.total_seconds"] == res.total_seconds
     assert validate_snapshot(snap) == []
 
 
-def test_count_roofline_attrs_on_a_chip(monkeypatch):
-    """On a device with published peaks a count job's span gets a bound and
-    a share of the whole mesh's peak; an unknown device raises."""
-    from types import SimpleNamespace
-
+@pytest.mark.parametrize("impl", ["jnp", "vertical"])
+def test_bytes_to_device_is_the_scatter_plus_every_payload(impl,
+                                                           monkeypatch):
+    """``MiningResult.bytes_to_device`` is the bytes of every array the
+    mine hands ``jax.device_put``: the scattered database and each count
+    job's payload; for the mask forms those are known from the shapes."""
     import jax
 
-    from repro.core.phases import count_roofline_attrs
-    chip = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
-    monkeypatch.setattr(jax, "devices", lambda *a: [chip])
-    runtime = SimpleNamespace(impl="vertical_pallas", _n_items=192,
-                              mesh=SimpleNamespace(size=4))
-    job = dict(n_candidates=4096, n_txns=200_000, n_words=6, kmax=3,
-               seconds=0.01)
-    attrs = count_roofline_attrs(runtime, **job)
-    assert attrs["roofline_bound"] == "memory"
-    assert attrs["roofline_peak"] == 4 * 819e9
-    assert 0.0 < attrs["roofline_peak_frac"] <= 1.0
-    monkeypatch.setattr(chip, "device_kind", "TPU v9 imaginary")
-    with pytest.raises(ValueError, match="no published peaks"):
-        count_roofline_attrs(runtime, **job)
+    from repro.core import mine
+    from repro.core.bitset import pack_itemsets
+    from repro.core.mapreduce import MapReduceRuntime
+    from repro.core.phases import bucket_pad
+    placed = []
+    real_put = jax.device_put
+
+    def spy(x, *a, **k):
+        placed.append(np.asarray(x).nbytes)
+        return real_put(x, *a, **k)
+
+    txns = _tiny_txns(4, n=80)
+    db = pack_itemsets(txns, 10)
+    rt = MapReduceRuntime(impl=impl, autotune=False)
+    monkeypatch.setattr(jax, "device_put", spy)
+    res = mine(db_masks=db, n_items=10, min_sup=0.2, runtime=rt,
+               elastic=False)
+    assert res.bytes_to_device == sum(placed) == rt.stats.bytes_to_device
+    assert len(placed) == 1 + res.dispatches
+    if impl == "jnp":
+        rows = sum(bucket_pad(np.zeros((sum(p.candidate_counts), 1),
+                                       np.uint32)).shape[0]
+                   for p in res.phases)
+        assert res.bytes_to_device == db.nbytes + rows * db.shape[1] * 4
+    # a second mine on the same runtime carries only its own bytes
+    placed.clear()
+    again = mine(db_masks=db, n_items=10, min_sup=0.2, runtime=rt,
+                 elastic=False)
+    assert again.bytes_to_device == sum(placed) == res.bytes_to_device
 
 
 def test_untraced_mine_records_nothing(fresh_registry):
