@@ -110,6 +110,23 @@ def lowest_bit_index(masks: np.ndarray) -> np.ndarray:
     return lo
 
 
+def lowest_bits(masks: np.ndarray, n: int):
+    """Yield each row's ``n`` lowest set bits, lowest first, as ``(word, bit)``:
+    ``(N,)`` word indices and ``(N,)`` uint32 one-bit words.
+
+    Word-level: one pass over ``(N, W)`` per bit, no ``(N, W*32)`` expansion.
+    A row with fewer than ``n`` bits yields a zero ``bit`` past its last one.
+    """
+    rest = np.array(masks, dtype=np.uint32)
+    rows = np.arange(rest.shape[0])
+    for _ in range(n):
+        word = np.argmax(rest != 0, axis=1)
+        w = rest[rows, word]
+        bit = w & -w
+        rest[rows, word] = w ^ bit
+        yield word, bit
+
+
 # ---------------------------------------------------------------------------
 # 64-bit order-independent-ish hashing of masks (host side, for membership).
 # Rows are hashed word-by-word with distinct odd multipliers, so the hash is a

@@ -8,7 +8,9 @@ Semantics match the classic Agrawal–Srikant generation exactly:
   Each ``(k+1)``-candidate is produced by exactly one unordered pair, so no
   dedup pass is needed and candidate counts are comparable to the paper's.
 * **prune** — drop a candidate if any of its ``k``-subsets is absent from the
-  previous level (the Apriori property).  ``non_apriori_gen`` skips this — the
+  previous level (the Apriori property).  Two of those subsets are the join's
+  parents, so only the ``k-1`` that drop a shared-prefix item are looked up —
+  none at level 2.  ``non_apriori_gen`` skips this — the
   paper's §4.2 optimization — producing a superset of un-pruned candidates whose
   false positives are eliminated by support counting (integrity preserved).
 
@@ -30,16 +32,10 @@ import dataclasses
 
 import numpy as np
 
-from .bitset import WORD_BITS, MaskIndex, highest_bit_index, lowest_bit_index
+from .bitset import (WORD_BITS, MaskIndex, highest_bit_index,
+                     lowest_bit_index, lowest_bits)
 
 _DEF_BLOCK = 1024
-
-
-def _bit_matrix(masks: np.ndarray) -> np.ndarray:
-    """(N, W) uint32 → (N, W*32) uint8 bit expansion (bit b of word w at w*32+b)."""
-    shifts = np.arange(WORD_BITS, dtype=np.uint32)
-    bits = (masks[:, :, None] >> shifts[None, None, :]) & np.uint32(1)
-    return bits.reshape(masks.shape[0], -1).astype(np.uint8)
 
 
 def _join_pairs_prefix(prev: np.ndarray):
@@ -170,20 +166,27 @@ def speculative_join(cands: np.ndarray, k: int,
 
 
 def prune(cands: np.ndarray, prev: np.ndarray, k_prev: int) -> np.ndarray:
-    """Apriori-property prune: keep candidates all of whose ``k_prev``-subsets ∈ prev."""
+    """Apriori-property prune: keep candidates all of whose ``k_prev``-subsets ∈ prev.
+
+    Precondition: ``cands`` is ``join(prev)``, or the subset of it that
+    ``SpecJoin.resolve`` returns.  A candidate is its two parents' shared
+    ``k_prev - 1`` lowest items plus each parent's highest item, so the two
+    subsets that drop one of its two highest items are the parents, in
+    ``prev`` by construction.  Only the ``k_prev - 1`` subsets that drop a
+    prefix item are looked up: ``len(cands) * (k_prev - 1)`` probes, none at
+    ``k_prev == 1``.  Rows keep their order.
+    """
     cands = np.asarray(cands, dtype=np.uint32)
-    if cands.shape[0] == 0:
+    if cands.shape[0] == 0 or k_prev < 2:
         return cands
     index = MaskIndex(prev)
-    bitmat = _bit_matrix(cands)
-    rows, cols = np.nonzero(bitmat)
-    subsets = cands[rows].copy()
-    subsets[np.arange(rows.size), cols // WORD_BITS] ^= (
-        np.uint32(1) << (cols % WORD_BITS).astype(np.uint32))
-    present = index.contains(subsets)
-    missing_per_row = np.bincount(rows, weights=(~present).astype(np.int64),
-                                  minlength=cands.shape[0])
-    return cands[missing_per_row == 0]
+    rows = np.arange(cands.shape[0])
+    keep = np.ones(cands.shape[0], dtype=bool)
+    for word, bit in lowest_bits(cands, k_prev - 1):
+        subsets = cands.copy()
+        subsets[rows, word] ^= bit
+        keep &= index.contains(subsets)
+    return cands[keep]
 
 
 def apriori_gen(prev: np.ndarray, k_prev: int, block: int = _DEF_BLOCK,

@@ -110,7 +110,7 @@ def run_phase(runtime: MapReduceRuntime, db_sharded, n_txns: int,
     t0 = time.perf_counter()
     levels_cands: list[np.ndarray] = []
     cur = prev_frequent
-    p, total, t_prune = 0, 0, 0.0
+    p, total, t_prune, probes = 0, 0, 0.0, 0
     gen_span = tracer.span("mine.gen", k_start=k_prev + 1)
     while True:
         if p == 0 and spec is not None and prev_keep is not None:
@@ -121,6 +121,7 @@ def run_phase(runtime: MapReduceRuntime, db_sharded, n_txns: int,
         if p == 0 or not optimized:
             # apriori-gen prunes; optimized phases skip it past the first
             # level (non-apriori-gen)
+            probes += cands.shape[0] * (k_prev + p - 1)   # prune's lookups
             tp = time.perf_counter()
             cands = prune(cands, cur, k_prev + p)
             t_prune += time.perf_counter() - tp
@@ -136,7 +137,7 @@ def run_phase(runtime: MapReduceRuntime, db_sharded, n_txns: int,
             break
     t_gen = time.perf_counter() - t0
     gen_span.set(n_levels=len(levels_cands), n_candidates=total,
-                 prune_seconds=t_prune).close()
+                 prune_seconds=t_prune, prune_probes=probes).close()
 
     if not levels_cands:
         return PhaseResult(k_prev + 1, 0, [], t_gen, 0.0,

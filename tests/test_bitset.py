@@ -5,8 +5,9 @@ import pytest
 pytest.importorskip("hypothesis")  # property tests degrade to skip without it
 from hypothesis import given, settings, strategies as st
 
-from repro.core.bitset import (MaskIndex, hash_rows, highest_bit_index,
-                               lowest_bit_index, n_words, pack_itemsets,
+from repro.core.bitset import (WORD_BITS, MaskIndex, hash_rows,
+                               highest_bit_index, lowest_bit_index,
+                               lowest_bits, n_words, pack_itemsets,
                                popcount_rows, singleton_masks, unpack_itemsets)
 
 itemsets_strategy = st.lists(
@@ -40,6 +41,21 @@ def test_hi_lo_bits(itemsets):
             assert hi[i] == max(t) and lo[i] == min(t)
         else:
             assert hi[i] == -1 and lo[i] > 91
+
+
+@given(itemsets_strategy)
+@settings(max_examples=30, deadline=None)
+def test_lowest_bits_yields_each_rows_lowest_items_in_order(itemsets):
+    masks = pack_itemsets(itemsets, 91)
+    before = masks.copy()
+    steps = list(lowest_bits(masks, 4))
+    np.testing.assert_array_equal(masks, before)      # input left as it was
+    assert len(steps) == 4
+    for i, t in enumerate(itemsets):
+        got = [int(word[i]) * WORD_BITS + int(bit[i]).bit_length() - 1
+               for word, bit in steps if bit[i]]
+        assert got == list(t[:4])
+        assert all(int(bit[i]) & (int(bit[i]) - 1) == 0 for _, bit in steps)
 
 
 def test_singleton_masks():

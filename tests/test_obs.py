@@ -375,6 +375,10 @@ def test_traced_mine_span_taxonomy_and_wallclock(fresh_registry):
     for g in gens:
         assert g.attrs["prune_seconds"] >= 0.0
         assert g.attrs["prune_seconds"] <= g.duration
+    # prune looks up only the non-parent subsets: none at level 2
+    assert all("prune_probes" in g.attrs for g in gens)
+    (level2,) = [g for g in gens if g.attrs["k_start"] == 2]
+    assert level2.attrs["prune_probes"] == 0
     assert not tr.events                  # no count.dispatch instants
     # registry mirrored the RuntimeStats increments 1:1
     assert fresh_registry.value("mine.dispatches") == res.dispatches
