@@ -53,6 +53,7 @@ class MiningResult:
     repartitions: int = 0           # elastic mesh re-layouts this run (§11)
     overlap_seconds: float = 0.0    # host gen time overlapped with counting jobs
     bytes_to_device: int = 0        # database and candidate bytes placed
+    exchange_bytes: int = 0         # count bytes entering the psums over data
     decisions: list = dataclasses.field(default_factory=list)
     # cost-controller telemetry rows for this run (DESIGN.md §9)
 
@@ -208,8 +209,13 @@ def mine(transactions=None, *, db_masks: np.ndarray | None = None,
     overlap_start = runtime.stats.overlap_seconds
     repartitions_start = runtime.stats.repartitions
     bytes_to_device_start = runtime.stats.bytes_to_device
-    with tracer.span("mine.scatter", n_txns=n_txns, n_words=n_words):
+    exchange_bytes_start = runtime.stats.exchange_bytes
+    pack_start = runtime.stats.pack_seconds
+    with tracer.span("mine.scatter", n_txns=n_txns, n_words=n_words,
+                     shards=runtime.n_data_shards) as scatter_span:
         db_sharded = runtime.scatter_db(db_masks, n_items=n_items)
+        scatter_span.set(
+            pack_seconds=runtime.stats.pack_seconds - pack_start)
     # re-pin: an "auto" runtime may have switched impl at scatter time
     controller.set_count_context(n_txns=n_txns, n_words=n_words,
                                  impl=runtime.impl,
@@ -280,7 +286,8 @@ def mine(transactions=None, *, db_masks: np.ndarray | None = None,
                 with tracer.span("mine.count.prep"):
                     padded = bucket_pad(singles)
                     payload = runtime.place_candidates(padded)
-                cspan.set(padded=int(padded.shape[0]))
+                cspan.set(padded=int(padded.shape[0]),
+                          exchange_bytes=runtime.exchange_bytes(payload))
                 fut = runtime.dispatch_count(
                     db_sharded, payload,
                     min_count=min_count if pipeline else None,
@@ -438,4 +445,5 @@ def mine(transactions=None, *, db_masks: np.ndarray | None = None,
         repartitions=runtime.stats.repartitions - repartitions_start,
         overlap_seconds=runtime.stats.overlap_seconds - overlap_start,
         bytes_to_device=runtime.stats.bytes_to_device - bytes_to_device_start,
+        exchange_bytes=runtime.stats.exchange_bytes - exchange_bytes_start,
         decisions=controller.decision_rows(decisions_mark))

@@ -70,9 +70,11 @@ class RuntimeStats:
     fused_dispatches: int = 0   # jobs that filtered on device
     overlap_seconds: float = 0.0  # host gen time spent while a job was in flight
     bytes_to_host: int = 0      # result bytes actually fetched from device
-    bytes_to_device: int = 0    # database and candidate bytes placed on devices
+    bytes_to_device: int = 0    # database and candidate bytes, per device copy
+    exchange_bytes: int = 0     # count bytes all devices put into the psums
     repartitions: int = 0       # elastic mesh re-layouts (DESIGN.md §11)
     scatter_seconds: float = 0.0  # host time spent (re-)placing the database
+    pack_seconds: float = 0.0   # of it, building the vertical per-shard bitmaps
 
     def __setattr__(self, name, value):
         # Mirror every increment into the process-wide metrics registry
@@ -271,14 +273,17 @@ class MapReduceRuntime:
         if self.vertical:
             assert self._n_items is not None, "vertical impl needs n_items"
             per = db_masks.shape[0] // d
+            t_pack = time.perf_counter()
             host = np.stack([
                 vertical_pack(db_masks[i * per:(i + 1) * per], self._n_items)
                 for i in range(d)])                      # (d, I+1, Tw)
+            self.stats.pack_seconds += time.perf_counter() - t_pack
             spec = P("data", None, None)
         else:
             host, spec = db_masks, P("data", None)
         out = jax.device_put(host, NamedSharding(self.mesh, spec))
-        self.stats.bytes_to_device += host.nbytes
+        # sharded over data, so every other mesh axis holds a copy
+        self.stats.bytes_to_device += host.nbytes * (self.mesh.size // d)
         self.stats.scatter_seconds += time.perf_counter() - t0
         return out
 
@@ -450,8 +455,20 @@ class MapReduceRuntime:
             host = np.asarray(cands_padded, dtype=np.uint32)
         spec = P(self.cand_axis, None) if self.cand_axis else P(None, None)
         payload = jax.device_put(host, NamedSharding(self.mesh, spec))
-        self.stats.bytes_to_device += host.nbytes
+        # each candidate shard goes to every device of the other mesh axes
+        self.stats.bytes_to_device += host.nbytes * (
+            self.mesh.size // self.n_cand_shards)
         return payload
+
+    def exchange_bytes(self, payload: jax.Array) -> int:
+        """Bytes of the int32 counts that all devices put into one job's
+        ``psum`` over ``data``: each device's rows of ``payload`` times 4 B,
+        over the whole mesh; 0 on a single data shard, where nothing is
+        exchanged."""
+        if self.n_data_shards == 1:
+            return 0
+        rows = int(payload.shape[0]) // self.n_cand_shards
+        return rows * 4 * self.mesh.size
 
     def dispatch_count(self, db_sharded, payload: jax.Array,
                        min_count: float | None = None,
@@ -489,6 +506,7 @@ class MapReduceRuntime:
         out = self._jitted[key](*args)
         self.stats.dispatches += 1
         self.stats.rows_counted += int(payload.shape[0])
+        self.stats.exchange_bytes += self.exchange_bytes(payload)
         if fused:
             self.stats.fused_dispatches += 1
         return CountFuture(self, out, fused=fused, with_counts=with_counts,

@@ -152,7 +152,8 @@ def run_phase(runtime: MapReduceRuntime, db_sharded, n_txns: int,
         padded = bucket_pad(all_cands, min_bucket)
         payload = runtime.place_candidates(padded)
     count_span.set(n_candidates=int(all_cands.shape[0]),
-                   padded=int(padded.shape[0]))
+                   padded=int(padded.shape[0]),
+                   exchange_bytes=runtime.exchange_bytes(payload))
     fut = runtime.dispatch_count(db_sharded, payload,
                                  min_count=min_count if fused else None,
                                  n_valid=all_cands.shape[0])

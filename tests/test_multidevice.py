@@ -368,3 +368,97 @@ def test_speedup_harness_runs():
             print("TIME", time.perf_counter() - t0, res.n_phases)
         """, n_devices=n)
         assert "TIME" in out
+
+
+_COUNTED_MINE = """
+    import jax, numpy as np
+    from repro.core import mine
+    from repro.core.mapreduce import MapReduceRuntime
+    from repro.launch.mesh import make_mining_mesh
+    from repro.obs.metrics import get_registry
+    from repro.obs.trace import Tracer, use_tracer
+    rng = np.random.default_rng(3)
+    base = rng.random((4, 24)) < 0.4
+    txns = [np.nonzero(np.where(rng.random(24) < 0.85, base[rng.integers(4)],
+                                rng.random(24) < 0.1))[0].tolist() or [0]
+            for _ in range(203)]
+    placed = []
+    put = jax.device_put
+
+    def spy(x, *a, **k):
+        placed.append(np.asarray(x).nbytes)
+        return put(x, *a, **k)
+    jax.device_put = spy
+
+    def counted(n_data, n_cand, impl):
+        rt = MapReduceRuntime(mesh=make_mining_mesh(n_data, n_cand),
+                              impl=impl, autotune=False,
+                              cand_axis="cand" if n_cand > 1 else None)
+        placed.clear()
+        tr = Tracer()
+        x0 = get_registry().value("mine.exchange_bytes") or 0
+        with use_tracer(tr):
+            res = mine(txns, n_items=24, min_sup=0.3, algorithm="spc",
+                       runtime=rt, elastic=False, pipeline=False,
+                       balance_shards_by_width=False)
+        x1 = get_registry().value("mine.exchange_bytes") or 0
+        return rt, res, tr, list(placed), x1 - x0
+"""
+
+
+def test_exchange_and_device_copies_counted_on_a_4x1_mesh():
+    """On four data shards every count job puts rows x 4 B into the psum on
+    each of the four chips, and each payload is placed once per chip; on a
+    2x2 candidate-sharded mesh a chip holds half the rows, and the
+    scatter goes to both candidate shards."""
+    out = run_py(_COUNTED_MINE + """
+    for n_data, n_cand, impl in [(4, 1, "vertical"), (4, 1, "jnp"),
+                                 (2, 2, "vertical")]:
+        rt, res, tr, placed, registry = counted(n_data, n_cand, impl)
+        jobs = [s for s in tr.spans if s.name == "mine.count"]
+        assert len(jobs) == res.dispatches >= 2
+        for s in jobs:
+            rows = s.attrs["padded"]
+            if n_cand > 1:
+                rows += (-rows) % (32 * n_cand)
+            assert s.attrs["exchange_bytes"] == rows // n_cand * 4 * 4, s.attrs
+        total = sum(s.attrs["exchange_bytes"] for s in jobs)
+        assert res.exchange_bytes == rt.stats.exchange_bytes == total > 0
+        assert registry == total
+        # the scatter, then one payload per job: the host arrays handed to
+        # device_put, times the chips that hold a copy of each
+        assert len(placed) == 1 + res.dispatches
+        assert res.bytes_to_device == rt.stats.bytes_to_device == (
+            placed[0] * n_cand + sum(placed[1:]) * n_data)
+    print("EXCHANGE_OK")
+    """, n_devices=4)
+    assert "EXCHANGE_OK" in out
+
+
+def test_one_device_exchanges_nothing_and_counts_each_array_once():
+    out = run_py(_COUNTED_MINE + """
+    for impl in ["vertical", "jnp"]:
+        rt, res, tr, placed, registry = counted(1, 1, impl)
+        assert res.exchange_bytes == rt.stats.exchange_bytes == 0
+        assert registry == 0
+        assert all(s.attrs["exchange_bytes"] == 0
+                   for s in tr.spans if s.name == "mine.count")
+        assert res.bytes_to_device == sum(placed) > 0
+    print("ONE_DEVICE_OK")
+    """, n_devices=1)
+    assert "ONE_DEVICE_OK" in out
+
+
+def test_scatter_span_carries_shards_and_pack_seconds():
+    out = run_py(_COUNTED_MINE + """
+    for impl in ["vertical", "jnp"]:
+        rt, res, tr, placed, registry = counted(4, 1, impl)
+        (s,) = [s for s in tr.spans if s.name == "mine.scatter"]
+        assert s.attrs["shards"] == 4
+        assert 0.0 <= s.attrs["pack_seconds"] <= s.duration
+        assert (s.attrs["pack_seconds"] > 0) == impl.startswith("vertical")
+        assert rt.stats.pack_seconds == s.attrs["pack_seconds"]
+        assert rt.stats.pack_seconds <= rt.stats.scatter_seconds
+    print("SCATTER_OK")
+    """, n_devices=4)
+    assert "SCATTER_OK" in out
