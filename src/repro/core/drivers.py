@@ -283,9 +283,12 @@ def mine(transactions=None, *, db_masks: np.ndarray | None = None,
             with tracer.span("mine.count", k_start=1, npass=1,
                              n_candidates=n_items, impl=runtime.impl,
                              fused=pipeline) as cspan:
-                with tracer.span("mine.count.prep"):
+                index_start = runtime.stats.index_seconds
+                with tracer.span("mine.count.prep") as prep_span:
                     padded = bucket_pad(singles)
                     payload = runtime.place_candidates(padded)
+                    prep_span.set(index_seconds=runtime.stats.index_seconds
+                                  - index_start)
                 cspan.set(padded=int(padded.shape[0]),
                           exchange_bytes=runtime.exchange_bytes(payload))
                 fut = runtime.dispatch_count(

@@ -53,7 +53,7 @@ from repro.kernels.autotune import defaults_fit, tuned_blocks
 from repro.obs.metrics import get_registry
 
 from .counting import local_counts, local_counts_vertical
-from .bitset import popcount_rows
+from .bitset import WORD_BITS, floor_log2
 
 IMPLS = ("jnp", "matmul", "pallas", "pallas_interpret",
          "matmul_pallas", "matmul_pallas_interpret",
@@ -75,6 +75,7 @@ class RuntimeStats:
     repartitions: int = 0       # elastic mesh re-layouts (DESIGN.md §11)
     scatter_seconds: float = 0.0  # host time spent (re-)placing the database
     pack_seconds: float = 0.0   # of it, building the vertical per-shard bitmaps
+    index_seconds: float = 0.0  # building the vertical forms' item index
 
     def __setattr__(self, name, value):
         # Mirror every increment into the process-wide metrics registry
@@ -414,21 +415,33 @@ class MapReduceRuntime:
         return jax.jit(fn)
 
     def _padded_indices(self, masks: np.ndarray) -> np.ndarray:
-        """(C, W) masks (zero rows allowed) → (C, kmax) item ids padded with
-        the valid-mask sentinel row (AND identity)."""
-        sentinel = self._n_items
-        pc = popcount_rows(masks)
-        kmax = max(int(pc.max()) if pc.size else 1, 1)
-        C = masks.shape[0]
-        from .bitset import WORD_BITS
-        shifts = np.arange(WORD_BITS, dtype=np.uint32)
-        bits = ((masks[:, :, None] >> shifts[None, None, :]) & np.uint32(1))
-        bits = bits.reshape(C, -1).astype(bool)
-        rows, cols = np.nonzero(bits)
-        idx = np.full((C, kmax), sentinel, np.int32)
-        starts = np.zeros(C + 1, np.int64)
-        np.cumsum(pc, out=starts[1:])
-        idx[rows, np.arange(rows.size) - starts[rows]] = cols
+        """(C, W) masks (zero rows allowed) → (C, kmax) item ids, ascending in
+        each row and padded with the valid-mask sentinel row (AND identity);
+        ``kmax`` is the largest popcount, at least 1.
+
+        Word by word: the nonzero words in row-major order, then their set
+        bits peeled lowest first (``w & -w``), so no array larger than
+        ``(C, W)`` is built."""
+        C, W = masks.shape
+        flat = np.flatnonzero(masks)
+        rows, words = np.divmod(flat, W)
+        w = masks.reshape(-1)[flat]
+        n_bits = np.bitwise_count(w).astype(np.int64)
+        # a word's first column: the set bits of its row's earlier words
+        before = np.cumsum(n_bits) - n_bits
+        first = np.ones(rows.size, bool)
+        first[1:] = rows[1:] != rows[:-1]
+        col = before - np.maximum.accumulate(np.where(first, before, 0))
+        kmax = max(int((col + n_bits).max()) if rows.size else 1, 1)
+        idx = np.full((C, kmax), self._n_items, np.int32)
+        item0 = words * WORD_BITS
+        while w.size:
+            bit = w & -w
+            idx[rows, col] = item0 + floor_log2(bit)
+            w ^= bit
+            col += 1
+            live = w != 0
+            rows, col, w, item0 = rows[live], col[live], w[live], item0[live]
         return idx
 
     def place_candidates(self, cands_padded: np.ndarray) -> jax.Array:
@@ -450,7 +463,9 @@ class MapReduceRuntime:
                     [cands_padded,
                      np.zeros((pad, cands_padded.shape[1]), np.uint32)])
         if self.vertical:
+            t_index = time.perf_counter()
             host = self._padded_indices(cands_padded)
+            self.stats.index_seconds += time.perf_counter() - t_index
         else:
             host = np.asarray(cands_padded, dtype=np.uint32)
         spec = P(self.cand_axis, None) if self.cand_axis else P(None, None)

@@ -147,10 +147,12 @@ def run_phase(runtime: MapReduceRuntime, db_sharded, n_txns: int,
     count_span = tracer.span(
         "mine.count", k_start=k_prev + 1, npass=len(levels_cands),
         impl=runtime.impl, fused=fused)
-    with tracer.span("mine.count.prep"):
+    index_start = runtime.stats.index_seconds
+    with tracer.span("mine.count.prep") as prep_span:
         all_cands = np.concatenate(levels_cands, axis=0)
         padded = bucket_pad(all_cands, min_bucket)
         payload = runtime.place_candidates(padded)
+        prep_span.set(index_seconds=runtime.stats.index_seconds - index_start)
     count_span.set(n_candidates=int(all_cands.shape[0]),
                    padded=int(padded.shape[0]),
                    exchange_bytes=runtime.exchange_bytes(payload))

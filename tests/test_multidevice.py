@@ -462,3 +462,42 @@ def test_scatter_span_carries_shards_and_pack_seconds():
     print("SCATTER_OK")
     """, n_devices=4)
     assert "SCATTER_OK" in out
+
+
+def test_place_candidates_pads_the_index_per_candidate_shard():
+    """On a 2x2 (data, cand) mesh the payload is padded to 32 rows per
+    candidate shard: every candidate's items in order, then sentinel rows
+    (the bucket pad and the shard pad), each shard holding its rows."""
+    out = run_py("""
+        import numpy as np
+        from repro.core.bitset import pack_itemsets
+        from repro.core.mapreduce import MapReduceRuntime
+        from repro.core.phases import bucket_pad
+        from repro.launch.mesh import make_mining_mesh
+        n_items = 70
+        rng = np.random.default_rng(5)
+        db = pack_itemsets([sorted(set(rng.integers(0, n_items, 9).tolist()))
+                            for _ in range(64)], n_items)
+        rt = MapReduceRuntime(mesh=make_mining_mesh(2, 2), impl="vertical",
+                              cand_axis="cand", autotune=False)
+        dbs = rt.scatter_db(db, n_items=n_items)
+        items = [sorted(rng.choice(n_items, k, replace=False).tolist())
+                 for k in rng.integers(1, 4, 20)]
+        cands = pack_itemsets(items, n_items)
+        padded = bucket_pad(cands, min_bucket=8)
+        assert padded.shape == (32, 3), padded.shape
+        payload = rt.place_candidates(padded)
+        index = np.asarray(payload)
+        assert index.shape == (64, 3) and index.dtype == np.int32
+        for row, its in zip(index, items):
+            assert row.tolist() == its + [n_items] * (3 - len(its))
+        assert (index[20:] == n_items).all()
+        for shard in payload.addressable_shards:
+            lo = shard.index[0].start or 0
+            assert (np.asarray(shard.data) == index[lo:lo + 32]).all()
+        counts = rt.phase_count(dbs, padded)[:20]
+        assert counts.tolist() == [int(((db & c) == c).all(axis=1).sum())
+                                   for c in cands]
+        print("CAND_INDEX_OK")
+    """, n_devices=4)
+    assert "CAND_INDEX_OK" in out

@@ -367,6 +367,8 @@ def test_traced_mine_span_taxonomy_and_wallclock(fresh_registry):
         assert [k.name for k in kids] == ["mine.count.prep",
                                           "mine.count.wait"]
         assert kids[0].t1 <= kids[1].t0
+        # the item index is built inside prep, and timed there
+        assert 0.0 <= kids[0].attrs["index_seconds"] <= kids[0].duration
         assert "count_seconds" in c.attrs
         assert not any(k.startswith("roofline_") for k in c.attrs)
     # nothing of the count job's host side falls between gen and count
