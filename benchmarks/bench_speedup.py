@@ -2,8 +2,8 @@
 
 Each device count runs in a subprocess with its own
 ``--xla_force_host_platform_device_count`` (the host-device simulation of a
-bigger cluster).  NOTE (recorded in EXPERIMENTS.md): on this 1-core container
-host devices time-share one CPU, so wall-clock speedup is expected to be flat —
+bigger cluster).  NOTE: on a one-core host the simulated devices
+time-share one CPU, so wall-clock speedup is expected to be flat —
 the benchmark validates the *harness* (shards scale, answers agree) and
 reports per-device work reduction; real scaling numbers need real chips.
 """

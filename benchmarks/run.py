@@ -8,10 +8,10 @@ import argparse
 import sys
 import time
 
-from . import (bench_candidates, bench_costmodel, bench_decode_fusion,
-               bench_exec_time, bench_kernels, bench_lk_counts,
-               bench_phase_breakdown, bench_rules, bench_scalability,
-               bench_scaling, bench_speedup, bench_stream)
+from . import (bench_candidates, bench_costmodel, bench_exec_time,
+               bench_kernels, bench_lk_counts, bench_phase_breakdown,
+               bench_rules, bench_scalability, bench_scaling, bench_speedup,
+               bench_stream)
 
 SUITES = {
     "exec_time": bench_exec_time,          # Figs. 2-4
@@ -20,7 +20,6 @@ SUITES = {
     "candidates": bench_candidates,        # Tables 7-9
     "scalability": bench_scalability,      # Fig. 5(a)
     "speedup": bench_speedup,              # Fig. 5(b)
-    "decode_fusion": bench_decode_fusion,  # beyond-paper serving fusion
     "kernels": bench_kernels,              # Pallas/counting microbench
     "rules": bench_rules,                  # rule generation + serving (§7)
     "stream": bench_stream,                # streaming incremental mining (§8)

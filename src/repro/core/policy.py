@@ -1,5 +1,5 @@
-"""Pass-combining width policies — shared by the mining drivers, the serving
-engine's multi-step decode fusion, and the training loop's microbatch fusion.
+"""Pass-combining width policies — shared by the mining drivers and the rule
+serving engine's micro-batch fusion (``serving/rules_engine.py``).
 
 Each policy decides, from the statistics of the two preceding phases, either a
 fixed number of passes for the next phase (``width``) or a candidate budget

@@ -15,8 +15,8 @@ The subsystem has three layers:
 Consumers: ``core/policy.MeasuredPolicy`` (pass combining),
 ``core/drivers.mine`` (calibration + speculative-join sizing),
 ``stream/miner.StreamMiner`` (re-mine trigger),
-``serving/rules_engine.RuleServeEngine`` and ``serving/engine.ServeEngine``
-(micro-batch fusion under a latency budget).
+``serving/rules_engine.RuleServeEngine`` (micro-batch fusion under a
+latency budget).
 """
 
 from .controller import CostController, Decision

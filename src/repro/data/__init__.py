@@ -1,4 +1,5 @@
-"""Data substrate: transaction datasets (paper) and LM token pipeline (framework)."""
+"""Data substrate: transaction datasets — synthetic generators, file I/O and
+shard balancing."""
 
 from .generator import ibm_generator, chess_like, mushroom_like, dataset_by_name
 from .loader import load_transactions, save_transactions, dataset_stats

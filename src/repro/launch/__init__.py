@@ -1,1 +1,1 @@
-# launcher package: mesh.py, dryrun.py, train.py, serve.py, mine.py
+# launcher package: mesh.py, mine.py, serve_rules.py, stream.py, report.py

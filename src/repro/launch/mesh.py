@@ -1,7 +1,7 @@
-"""Production mesh builders.
+"""Multi-host start-up and the 2-D ``(data, cand)`` mining mesh.
 
-NOTE: importing this module never touches jax device state; meshes are built
-only when the functions are called (after the launcher has set XLA_FLAGS).
+NOTE: importing this module never touches jax device state; the mesh is built
+only when ``make_mining_mesh`` is called (after the launcher has set XLA_FLAGS).
 """
 
 from __future__ import annotations
@@ -11,22 +11,6 @@ import os
 import jax
 
 from repro.compat import make_mesh
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16×16 single-pod (256 chips) or 2×16×16 two-pod (512 chips) mesh.
-
-    Axes: (data, model) single-pod; (pod, data, model) multi-pod — the pod
-    axis folds into data parallelism (see repro.sharding.physical_axis).
-    """
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def make_local_mesh(axis: str = "data"):
-    """1-D mesh over all local devices (tests / CPU benches / mining)."""
-    return make_mesh((len(jax.devices()),), (axis,))
 
 
 def init_distributed(coordinator: str | None = None,

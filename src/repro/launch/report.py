@@ -1,9 +1,7 @@
-"""Generate EXPERIMENTS.md §Dry-run / §Roofline tables from dryrun.jsonl —
-with ``--decisions``, the cost-model §Decisions table (DESIGN.md §9) — and
-with ``--trace``, the top-slowest-spans + per-phase breakdown of a
-``--trace-out`` file (DESIGN.md §13).
+"""Render the cost-model §Decisions table (DESIGN.md §9) with
+``--decisions``, and the top-slowest-spans + per-phase breakdown of a
+``--trace-out`` file (DESIGN.md §13) with ``--trace``.
 
-  PYTHONPATH=src python -m repro.launch.report results/dryrun.jsonl
   PYTHONPATH=src python -m repro.launch.report --decisions results/decisions.jsonl
   PYTHONPATH=src python -m repro.launch.report --trace trace.json
 
@@ -18,74 +16,8 @@ import json
 from collections import defaultdict
 
 
-def fmt_bytes(b):
-    return f"{b/2**30:.2f}"
-
-
 def fmt_s(x):
     return f"{x:.3e}"
-
-
-def load(path):
-    rows = [json.loads(l) for l in open(path)]
-    dedup = {}
-    for r in rows:  # last write wins per cell
-        dedup[(r["arch"], r["shape"], r["mesh"])] = r
-    return dedup
-
-
-def dryrun_table(cells) -> str:
-    out = ["| arch | shape | mesh | status | compile s | temp GiB/dev | "
-           "args GiB/dev | HLO GFLOPs (raw) | collectives (per-chip MB) |",
-           "|---|---|---|---|---|---|---|---|---|"]
-    for (arch, shape, mesh), r in sorted(cells.items()):
-        if r.get("skipped"):
-            out.append(f"| {arch} | {shape} | {mesh} | SKIP (full-attn) | – | – | – | – | – |")
-            continue
-        if not r.get("ok"):
-            out.append(f"| {arch} | {shape} | {mesh} | **FAIL** | – | – | – | – | – |")
-            continue
-        coll = ", ".join(f"{k}:{v/2**20:.0f}" for k, v in
-                         sorted(r["collectives_by_op"].items()))
-        out.append(
-            f"| {arch} | {shape} | {mesh} | ok | {r['compile_s']} | "
-            f"{fmt_bytes(r['temp_bytes_per_dev'])} | "
-            f"{fmt_bytes(r['arg_bytes_per_dev'])} | "
-            f"{r['hlo_flops_raw']/1e9:.1f} | {coll or '—'} |")
-    return "\n".join(out)
-
-
-def roofline_table(cells) -> str:
-    out = ["| arch | shape | compute s | memory s | collective s | dominant | "
-           "MODEL_FLOPS | useful ratio | bound by |",
-           "|---|---|---|---|---|---|---|---|---|"]
-    for (arch, shape, mesh), r in sorted(cells.items()):
-        if mesh != "16x16" or not r.get("ok"):
-            continue
-        t = r["roofline"]
-        bound = {"compute": "MXU/VPU", "memory": "HBM bw",
-                 "collective": "ICI"}[t["dominant"]]
-        out.append(
-            f"| {arch} | {shape} | {fmt_s(t['compute_s'])} | "
-            f"{fmt_s(t['memory_s'])} | {fmt_s(t['collective_s'])} | "
-            f"**{t['dominant']}** | {t['model_flops']:.2e} | "
-            f"{t['useful_ratio']:.2f} | {bound} |")
-    return "\n".join(out)
-
-
-def pick_hillclimb(cells):
-    """worst roofline balance, most collective-bound, most paper-representative."""
-    live = {k: v for k, v in cells.items()
-            if k[2] == "16x16" and v.get("ok")}
-    def frac(r):
-        t = r["roofline"]
-        dom = max(t["compute_s"], t["memory_s"], t["collective_s"])
-        return t["compute_s"] / dom if dom else 0.0
-    worst = min(live.items(), key=lambda kv: frac(kv[1]))
-    coll = max(live.items(), key=lambda kv: (
-        kv[1]["roofline"]["collective_s"]
-        / max(kv[1]["roofline"]["compute_s"], 1e-12)))
-    return worst[0], coll[0]
 
 
 def decision_table(rows) -> str:
@@ -254,36 +186,21 @@ def report_decisions(path):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("path", nargs="?", default="results/dryrun.jsonl")
-    ap.add_argument("--decisions", metavar="JSONL", default=None,
-                    help="render the cost-model decision telemetry table from "
-                         "a jsonl of CostController.decision_rows dicts or a "
-                         "mine/stream/serve_rules --json-out file")
-    ap.add_argument("--trace", metavar="JSON", default=None,
-                    help="render top-slowest-spans + per-phase breakdown "
-                         "from a --trace-out Chrome-trace file")
+    what = ap.add_mutually_exclusive_group(required=True)
+    what.add_argument("--decisions", metavar="JSONL", default=None,
+                      help="render the cost-model decision telemetry table "
+                           "from a jsonl of CostController.decision_rows "
+                           "dicts or a mine/stream/serve_rules --json-out file")
+    what.add_argument("--trace", metavar="JSON", default=None,
+                      help="render top-slowest-spans + per-phase breakdown "
+                           "from a --trace-out Chrome-trace file")
     ap.add_argument("--top", type=int, default=15,
                     help="rows in the --trace slowest-spans table")
     args = ap.parse_args()
     if args.trace:
         report_trace(args.trace, top=args.top)
-        return
-    if args.decisions:
+    else:
         report_decisions(args.decisions)
-        return
-    cells = load(args.path)
-    n_ok = sum(1 for r in cells.values() if r.get("ok"))
-    n_skip = sum(1 for r in cells.values() if r.get("skipped"))
-    n_fail = len(cells) - n_ok - n_skip
-    print(f"## Dry-run status: {n_ok} ok / {n_skip} skipped / {n_fail} failed "
-          f"({len(cells)} cells)\n")
-    print(dryrun_table(cells))
-    print()
-    print("## Roofline (single-pod 16×16)\n")
-    print(roofline_table(cells))
-    print()
-    worst, coll = pick_hillclimb(cells)
-    print(f"hillclimb candidates: worst-fraction={worst}, most-collective={coll}")
 
 
 if __name__ == "__main__":

@@ -1,39 +1,15 @@
-"""Roofline analysis from compiled dry-run artifacts.
+"""Counting-side cost basis and roofline (DESIGN.md §9-§10).
 
-Three terms per (arch × shape × mesh):
-
-  compute_s    = FLOPs / (chips × 197 TFLOP/s bf16)
-  memory_s     = HBM bytes / (chips × 819 GB/s)
-  collective_s = per-chip communicated bytes / (50 GB/s/link)
-
-Sources and caveats (see EXPERIMENTS.md §Methodology):
-
-* ``collective_bytes`` is parsed from the compiled SPMD HLO: every
-  all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute,
-  with ring-algorithm byte multipliers and **while-loop trip-count
-  attribution** — XLA's cost_analysis counts a while body once, so each
-  computation's contribution is multiplied by its loop trip count (parsed
-  from the loop-condition constant), which matters because all layers live
-  inside a `lax.scan`.
-* compute/memory use exact analytic accounting from the model config
-  (6·N·D weight FLOPs (+ attention/SSD terms), parameter+optimizer+activation
-  HBM traffic).  Raw ``cost_analysis`` numbers are recorded alongside, with
-  the loop-undercount caveat.
-* The CPU backend legalizes some bf16 dots to f32, so ``memory_analysis``
-  per-device bytes are up to ~2× pessimistic vs TPU for matmul-adjacent
-  temporaries; raw values are reported as upper bounds.
+* ``count_job_ops`` prices one support-counting job in the measured-ops basis
+  the cost models fit against (``XFER_OPS_PER_BYTE`` folds the job's
+  device→host traffic into the same basis).
+* ``count_kernel_roofline`` compares a counting job's measured time with the
+  chip's published peaks (``COUNT_PEAKS``): the int8 matmul peak for the
+  bit-plane matmul forms, HBM bandwidth for the popcount forms.
+* ``predicted_vs_achieved`` is one cost-model telemetry row.
 """
 
 from __future__ import annotations
-
-import dataclasses
-import re
-
-HW = {
-    "peak_flops": 197e12,   # bf16 per chip (TPU v5e-class)
-    "hbm_bw": 819e9,        # bytes/s per chip
-    "link_bw": 50e9,        # bytes/s per ICI link
-}
 
 
 # -- measured-ops basis for the counting side (DESIGN.md §9) -------------------
@@ -158,229 +134,3 @@ def predicted_vs_achieved(predicted_s: float, achieved_s: float) -> dict:
                if achieved_s > 0 else float("inf"))
     return {"predicted_s": float(predicted_s), "achieved_s": float(achieved_s),
             "ratio": float(ratio), "abs_rel_err": float(rel_err)}
-
-_DTYPE_BYTES = {
-    "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
-    "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8,
-}
-
-_COLL_RE = re.compile(
-    r"=\s*(\([^)]*\)|\S+)\s+"
-    r"(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
-    r"(-start)?\(")
-_SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-_COMP_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s+\([^)]*\)\s*->", re.M)
-_CALL_RE = re.compile(
-    r"(?:body=|condition=|calls=|to_apply=|branch_computations=\{)\s*%?([\w\.\-]+)")
-
-
-def _shape_bytes(shape_str: str) -> int:
-    total = 0
-    for dtype, dims in _SHAPE_RE.findall(shape_str):
-        if dtype not in _DTYPE_BYTES:
-            continue
-        n = 1
-        for d in dims.split(","):
-            if d:
-                n *= int(d)
-        total += n * _DTYPE_BYTES[dtype]
-    return total
-
-
-def _split_computations(hlo: str) -> dict:
-    """Split HLO text into {computation_name: body_text}."""
-    comps = {}
-    cur_name, cur_lines = None, []
-    for line in hlo.splitlines():
-        m = re.match(r"^(?:ENTRY\s+)?%?([\w\.\-]+)\s+\([^)]*\)\s*->.*\{", line)
-        if m:
-            if cur_name:
-                comps[cur_name] = "\n".join(cur_lines)
-            cur_name, cur_lines = m.group(1), [line]
-        elif cur_name is not None:
-            cur_lines.append(line)
-            if line.startswith("}"):
-                comps[cur_name] = "\n".join(cur_lines)
-                cur_name, cur_lines = None, []
-    if cur_name:
-        comps[cur_name] = "\n".join(cur_lines)
-    return comps
-
-
-def _trip_count(cond_text: str) -> int:
-    """Heuristic scan trip count: the largest s32 constant in the condition."""
-    consts = [int(c) for c in re.findall(r"constant\((\d+)\)", cond_text)]
-    return max(consts) if consts else 1
-
-
-def _group_size(line: str, n_devices: int) -> int:
-    m = re.search(r"replica_groups=\[(\d+),(\d+)\]", line)
-    if m:
-        return int(m.group(2))
-    m = re.search(r"replica_groups=\{\{([\d,]+)\}", line)
-    if m:
-        return len(m.group(1).split(","))
-    return n_devices
-
-
-def _comm_factor(op: str, g: int) -> float:
-    """Per-chip communicated bytes as a multiple of the tensor bytes (ring)."""
-    if g <= 1:
-        return 0.0
-    if op == "all-gather":
-        return (g - 1) / g
-    if op == "all-reduce":
-        return 2 * (g - 1) / g
-    if op == "reduce-scatter":
-        return (g - 1) / g
-    if op == "all-to-all":
-        return (g - 1) / g
-    if op == "collective-permute":
-        return 1.0
-    return 1.0
-
-
-def parse_collectives(hlo: str, n_devices: int) -> dict:
-    """Per-chip communicated bytes by collective op, trip-count weighted."""
-    comps = _split_computations(hlo)
-
-    # computation multipliers: ENTRY ×1; while bodies × trip count
-    mult = {}
-    entry = None
-    for name in comps:
-        if re.search(rf"^ENTRY\s+%?{re.escape(name)}\b", hlo, re.M):
-            entry = name
-    order = [(entry or next(iter(comps)), 1.0)]
-    seen = set()
-    while order:
-        name, m = order.pop()
-        if name in seen or name not in comps:
-            continue
-        seen.add(name)
-        mult[name] = mult.get(name, 0.0) + m
-        body = comps[name]
-        # while ops: body gets ×trip, condition ×trip
-        for wm in re.finditer(
-                r"while\(.*?condition=%?([\w\.\-]+),\s*body=%?([\w\.\-]+)", body):
-            cond, wbody = wm.group(1), wm.group(2)
-            trip = _trip_count(comps.get(cond, ""))
-            order.append((wbody, m * trip))
-            order.append((cond, m * trip))
-        for cm in _CALL_RE.finditer(body):
-            callee = cm.group(1)
-            if callee not in seen and not body.count(f"body=%{callee}"):
-                order.append((callee, m))
-
-    by_op: dict = {}
-    total = 0.0
-    for name, m in mult.items():
-        for line in comps[name].splitlines():
-            cm = _COLL_RE.search(line)
-            if not cm:
-                continue
-            shape_str, op = cm.group(1), cm.group(2)
-            b = _shape_bytes(shape_str)
-            g = _group_size(line, n_devices)
-            comm = b * _comm_factor(op, g) * m
-            by_op[op] = by_op.get(op, 0.0) + comm
-            total += comm
-    return {"by_op": by_op, "per_chip_bytes": total}
-
-
-# -- analytic FLOPs / bytes ----------------------------------------------------
-
-def analytic_flops(cfg, shape) -> dict:
-    """Exact-form FLOP accounting for one step of the given kind."""
-    tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
-    n_active = cfg.active_param_count()
-    n_total = cfg.param_count()
-    weight_flops_fwd = 2 * n_active * tokens
-
-    # attention: 2·S_ctx·hd FLOPs per (token, head) for qk plus same for pv
-    hd = cfg.resolved_head_dim
-    n_attn_layers = sum(1 for i in range(cfg.n_layers) if cfg.layer_kind(i) == "attn")
-    n_attn_layers += cfg.n_encoder_layers
-    if shape.kind == "decode":
-        ctx_len = shape.seq_len
-        attn_fwd = 4 * ctx_len * cfg.padded_heads * hd * n_attn_layers * shape.global_batch
-    else:
-        ctx_avg = shape.seq_len / 2
-        attn_fwd = 4 * ctx_avg * cfg.padded_heads * hd * n_attn_layers * tokens
-
-    # SSD: per token·head: intra-chunk ≈ 2·L·(N + hd) + state update 2·N·hd
-    ssd_fwd = 0
-    if cfg.ssm_state:
-        from repro.models.ssm import ssm_dims
-        d_inner, H, Pd, N = ssm_dims(cfg)
-        n_ssm = sum(1 for i in range(cfg.n_layers) if cfg.layer_kind(i) == "ssm")
-        if shape.kind == "decode":
-            ssd_fwd = 2 * H * Pd * N * 2 * n_ssm * shape.global_batch
-        else:
-            L = 256
-            ssd_fwd = (2 * L * (N + Pd) + 4 * N * Pd) * H * n_ssm * tokens
-
-    fwd = weight_flops_fwd + attn_fwd + ssd_fwd
-    if shape.kind == "train":
-        total = 3 * fwd          # bwd ≈ 2× fwd
-        # remat recompute: full policy re-runs the forward; "dots" saves
-        # matmul outputs and only recomputes elementwise glue (~15%)
-        total += fwd if getattr(cfg, "remat_policy", "full") == "full" else 0.15 * fwd
-        model_flops = 6 * n_active * tokens
-    else:
-        total = fwd
-        model_flops = 2 * n_active * tokens
-    return {"model_flops": float(model_flops), "total_flops": float(total),
-            "fwd_flops": float(fwd), "tokens": tokens,
-            "params_total": n_total, "params_active": n_active}
-
-
-def analytic_bytes(cfg, shape, chips: int) -> float:
-    """Per-step global HBM traffic (bytes), all chips combined."""
-    n = cfg.param_count()
-    tokens = shape.global_batch * (shape.seq_len if shape.kind != "decode" else 1)
-    act_unit = tokens * cfg.d_model * 2  # bf16 residual
-    layers = cfg.n_layers + cfg.n_encoder_layers
-    if shape.kind == "train":
-        # params read (fwd+bwd+remat) ×3, grads written, opt m/v read+write f32,
-        # master update; remat-saved activations written+read
-        weight_traffic = n * 2 * 3 + n * 2 + 4 * n * 4
-        act_traffic = act_unit * layers * (2 + 10)  # saves + working set approx
-        return float(weight_traffic + act_traffic)
-    if shape.kind == "prefill":
-        weight_traffic = n * 2
-        act_traffic = act_unit * layers * 6
-        return float(weight_traffic + act_traffic)
-    # decode: whole weight set + KV cache read per token step
-    hd = cfg.resolved_head_dim
-    n_attn = sum(1 for i in range(cfg.n_layers) if cfg.layer_kind(i) == "attn")
-    kv_bytes = (2 * shape.seq_len * cfg.n_kv_heads * hd * n_attn
-                * shape.global_batch * 2)
-    return float(cfg.active_param_count() * 2 + kv_bytes)
-
-
-@dataclasses.dataclass
-class RooflineTerms:
-    compute_s: float
-    memory_s: float
-    collective_s: float
-    dominant: str
-    model_flops: float
-    hlo_flops_raw: float
-    useful_ratio: float
-
-    def as_dict(self):
-        return dataclasses.asdict(self)
-
-
-def roofline_terms(cfg, shape, chips: int, collective_per_chip_bytes: float,
-                   hlo_flops_raw: float = 0.0) -> RooflineTerms:
-    fl = analytic_flops(cfg, shape)
-    by = analytic_bytes(cfg, shape, chips)
-    compute_s = fl["total_flops"] / (chips * HW["peak_flops"])
-    memory_s = by / (chips * HW["hbm_bw"])
-    collective_s = collective_per_chip_bytes / HW["link_bw"]
-    terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
-    dominant = max(terms, key=terms.get)
-    useful = fl["model_flops"] / fl["total_flops"] if fl["total_flops"] else 0.0
-    return RooflineTerms(compute_s, memory_s, collective_s, dominant,
-                         fl["model_flops"], hlo_flops_raw, useful)

@@ -1,13 +1,11 @@
 from .admission import (OpenLoopServer, QueryOutcome, ResultCache,
                         basket_key)
 from .common import outcome_summary
-from .engine import ServeEngine, ServePhaseRecord
 from .rule_store import DEFAULT_TENANT, ArenaState, RuleStore
 from .rules_engine import (Recommendation, RuleServeEngine, RuleServeRecord,
                            RULE_IMPLS)
 
-__all__ = ["ServeEngine", "ServePhaseRecord",
-           "Recommendation", "RuleServeEngine", "RuleServeRecord",
+__all__ = ["Recommendation", "RuleServeEngine", "RuleServeRecord",
            "RULE_IMPLS",
            "RuleStore", "ArenaState", "DEFAULT_TENANT",
            "OpenLoopServer", "QueryOutcome", "ResultCache", "basket_key",

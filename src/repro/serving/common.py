@@ -3,9 +3,7 @@
 Query bit-packing, pow2 query-shape bucketing and the per-query latency
 roll-up used to be private to ``serving/rules_engine.py`` and re-derived by
 every CLI/benchmark that reported percentiles; the streaming subsystem adds a
-third consumer, so they live here once.  ``ServeEngine`` (LM decode) shares
-the policy machinery through ``core/policy.py`` and the shape-bucket idea
-through :func:`bucket_rows`.
+third consumer, so they live here once.
 """
 
 from __future__ import annotations

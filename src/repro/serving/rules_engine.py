@@ -10,8 +10,8 @@ confidence·lift score matrix and reduces it with a device-side
 ``lax.top_k``; only the (Q, k) winners cross back to the host.
 
 Micro-batching: queued query batches are fused per dispatch by the same
-pass-combining ``Policy`` objects the mining drivers and the LM
-:class:`~repro.serving.engine.ServeEngine` share (``core/policy.py``).  The
+pass-combining ``Policy`` objects the mining drivers use
+(``core/policy.py``).  The
 isomorphism: one dispatch answering ``npass`` queued batches is the serving
 analogue of one counting job covering ``npass`` Apriori levels — candidate
 count |C| maps to rule·query pairs scored, |L| to queries answered.  The SPC

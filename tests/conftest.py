@@ -1,5 +1,5 @@
 import os
 import sys
 
-# tests see ONE device; the dry-run (and only it) forces 512 (assignment rule)
+# tests see ONE device; multi-device tests force more in their own subprocesses
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

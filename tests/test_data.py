@@ -1,5 +1,4 @@
-"""Data substrate: generator signatures, loader roundtrip, shard balancing,
-token pipeline determinism."""
+"""Data substrate: generator signatures, loader roundtrip, shard balancing."""
 
 import numpy as np
 
@@ -8,7 +7,6 @@ from repro.data import (chess_like, dataset_by_name, dataset_stats,
                         save_transactions)
 from repro.core.bitset import pack_itemsets, popcount_rows
 from repro.data.loader import balance_masks, balance_shards, shard_width_loads
-from repro.data.tokens import TokenPipeline
 
 
 def test_ibm_generator_signature():
@@ -117,21 +115,3 @@ def test_shard_width_loads_matches_contiguous_slices():
     expect = [popcount_rows(masks[i * per:(i + 1) * per]).sum()
               for i in range(4)]
     assert loads.tolist() == [float(x) for x in expect]
-
-
-def test_token_pipeline_shapes_and_determinism():
-    p1 = TokenPipeline(vocab_size=1000, seq_len=16, global_batch=4, seed=7)
-    p2 = TokenPipeline(vocab_size=1000, seq_len=16, global_batch=4, seed=7)
-    t1, l1 = p1.next_batch()
-    t2, l2 = p2.next_batch()
-    assert t1.shape == (4, 16) and (t1 == t2).all() and (l1 == l2).all()
-    assert (t1[:, 1:] == l1[:, :-1]).all()          # labels are next tokens
-
-
-def test_token_pipeline_sharding():
-    full = TokenPipeline(vocab_size=1000, seq_len=8, global_batch=8, seed=3)
-    s0 = TokenPipeline(vocab_size=1000, seq_len=8, global_batch=8, seed=3,
-                       shard_index=0, shard_count=2)
-    assert s0.local_batch == 4
-    t, _ = s0.next_batch()
-    assert t.shape == (4, 8)

@@ -1,8 +1,8 @@
 """Multi-device behaviour via subprocesses (XLA_FLAGS host device count).
 
-These run the real shard_map/pjit paths on 8 simulated devices: distributed
-mining parity, EP-MoE parity vs single device, elastic checkpoint reshard,
-and a miniature dry-run through the production launcher code path.
+These run the real shard_map/pjit paths on simulated host devices: distributed
+mining parity, the 2-D (data, cand) decomposition, elastic repartitioning,
+retry after an injected failure, shard balancing and the exchange counters.
 """
 
 import json
@@ -47,86 +47,6 @@ def test_mining_parity_on_8_devices():
         print("PARITY_OK")
     """)
     assert "PARITY_OK" in out
-
-
-def test_ep_moe_matches_single_device():
-    out = run_py("""
-        import jax, numpy as np, dataclasses
-        import jax.numpy as jnp
-        from repro.configs import get_config
-        from repro.models.moe import moe_init, moe_apply, _moe_apply_global
-        from repro.models.model import ShardCtx
-        from repro import sharding
-        from repro.compat import make_mesh
-        cfg = dataclasses.replace(get_config("qwen3-moe-30b-a3b", smoke=True),
-                                  capacity_factor=8.0)
-        p, _ = moe_init(jax.random.PRNGKey(0), cfg)
-        mesh = make_mesh((2, 4), ("data", "model"))
-        ctx = ShardCtx(mesh, sharding.make_rules())
-        x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
-                              jnp.float32).astype(jnp.bfloat16)
-        y_ep, aux_ep = jax.jit(lambda p, x: moe_apply(p, x, cfg, ctx))(p, x)
-        y_g, aux_g = jax.jit(lambda p, x: _moe_apply_global(p, x, cfg, None))(p, x)
-        err = float(jnp.max(jnp.abs(y_ep.astype(jnp.float32) - y_g.astype(jnp.float32))))
-        scale = float(jnp.max(jnp.abs(y_g.astype(jnp.float32)))) + 1e-9
-        assert err / scale < 0.05, (err, scale)
-        print("EP_OK", err/scale)
-    """)
-    assert "EP_OK" in out
-
-
-def test_elastic_reshard_8_to_4():
-    out = run_py("""
-        import jax, os, tempfile, numpy as np
-        from repro.configs import get_config
-        from repro.models import build_model
-        from repro.optim import AdamWConfig
-        from repro.train import init_train_state, save_checkpoint
-        from repro.train.elastic import restore_elastic
-        from repro import sharding
-        from repro.compat import make_mesh
-        model = build_model(get_config("smollm-135m", smoke=True))
-        opt = AdamWConfig()
-        rules = sharding.make_rules()
-        mesh8 = make_mesh((4, 2), ("data", "model"))
-        state = init_train_state(model, opt, jax.random.PRNGKey(0), mesh8, rules)
-        d = tempfile.mkdtemp()
-        save_checkpoint(d, 5, state)
-        # restore onto a DIFFERENT mesh (2x2 = "scale down to 4 devices")
-        mesh4 = make_mesh((2, 2), ("data", "model"))
-        tmpl = jax.tree.map(lambda x: x, state)
-        state4, step = restore_elastic(d, model, opt, mesh4, rules, tmpl)
-        assert step == 5
-        a = np.asarray(jax.device_get(state["params"]["embed"]["table"]), np.float32)
-        b = np.asarray(jax.device_get(state4["params"]["embed"]["table"]), np.float32)
-        assert (a == b).all()
-        print("ELASTIC_OK")
-    """)
-    assert "ELASTIC_OK" in out
-
-
-def test_mini_dryrun_multipod_codepath():
-    """The production dryrun code path on a small mesh: lower+compile train
-    and decode for a smoke arch on (pod, data, model) axes."""
-    out = run_py("""
-        import jax, jax.numpy as jnp
-        from repro.configs import get_config, ShapeConfig
-        from repro.models import build_model
-        from repro import sharding
-        from repro.launch.dryrun import build_step
-        from repro.compat import make_mesh
-        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
-        rules = sharding.make_rules()
-        model = build_model(get_config("smollm-135m", smoke=True))
-        for shape in [ShapeConfig("t", 32, 8, "train"),
-                      ShapeConfig("p", 32, 8, "prefill"),
-                      ShapeConfig("d", 64, 8, "decode")]:
-            fn, ex, _, _ = build_step(model, shape, mesh, rules)
-            compiled = fn.lower(*ex).compile()
-            assert compiled.memory_analysis() is not None
-        print("MINIDRY_OK")
-    """)
-    assert "MINIDRY_OK" in out
 
 
 def test_2d_candidate_decomposition():
@@ -307,51 +227,6 @@ def test_balanced_shards_mining():
         print("BALANCED_OK")
     """)
     assert "BALANCED_OK" in out
-
-
-def test_decode_profile_parity():
-    """The §Perf `decode` sharding profile (weights replicated over data,
-    KV-seq on model) preserves decode semantics: prefill + decode-step logits
-    match the unsharded run up to bf16 reduction-order noise."""
-    out = run_py("""
-        import jax, numpy as np
-        import jax.numpy as jnp
-        from repro.configs import get_config
-        from repro.models import build_model
-        from repro.models.model import ShardCtx
-        from repro import sharding
-        cfg = get_config("smollm-135m", smoke=True)
-        model = build_model(cfg)
-        params = model.init(jax.random.PRNGKey(0))
-        B, S, steps = 4, 8, 3
-        toks = np.random.default_rng(0).integers(1, cfg.vocab_size, (B, S)).astype(np.int32)
-
-        forced = np.random.default_rng(1).integers(
-            1, cfg.vocab_size, (steps, B)).astype(np.int32)
-
-        def rollout(ctx):
-            # teacher-forced so numeric tie-flips cannot compound
-            batch = {"tokens": jnp.asarray(toks)}
-            lgs = []
-            lg, caches = model.prefill(params, batch, cache_len=S+steps, ctx=ctx)
-            lgs.append(np.asarray(lg))
-            for t in range(steps - 1):
-                cur = jnp.asarray(forced[t])
-                lg, caches = model.decode_step(params, caches, cur[:, None],
-                                               jnp.full((B,), S+t, jnp.int32), ctx)
-                lgs.append(np.asarray(lg))
-            return np.stack(lgs)
-
-        base = rollout(ShardCtx(None, None))
-        from repro.compat import make_mesh
-        mesh = make_mesh((2, 4), ("data", "model"))
-        rules = sharding.make_rules("decode")
-        sharded = rollout(ShardCtx(mesh, rules))
-        err = np.abs(base - sharded)[:, :, :cfg.vocab_size].max()
-        assert err < 0.05, err
-        print("DECODE_PROFILE_OK", err)
-    """)
-    assert "DECODE_PROFILE_OK" in out
 
 
 def test_speedup_harness_runs():

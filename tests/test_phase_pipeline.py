@@ -1,5 +1,6 @@
-"""Device-resident phase pipeline: cross-impl equivalence, fused-vs-unfused
-counting, async futures, speculative join, and the block autotuner."""
+"""Device-resident phase pipeline: fused-vs-unfused counting, async futures,
+speculative join, and the block autotuner (cross-form equivalence against the
+oracle lives in test_mine_forms.py)."""
 
 import json
 import os
@@ -7,26 +8,12 @@ import os
 import numpy as np
 import pytest
 
+from minedata import planted_baskets
 from repro.core import mine
 from repro.core.bitset import pack_itemsets
 from repro.core.candidates import join_pairs, speculative_join
 from repro.core.mapreduce import MapReduceRuntime
 from repro.core.phases import bucket_pad
-from repro.core.policy import ALGORITHMS
-
-ALGOS = sorted(ALGORITHMS)
-IMPLS = ["jnp", "pallas_interpret", "vertical", "vertical_pallas_interpret"]
-
-
-def _dataset(seed=0, n=90, n_items=20):
-    rng = np.random.default_rng(seed)
-    base = rng.random((4, n_items)) < 0.4
-    txns = []
-    for _ in range(n):
-        pat = base[rng.integers(4)]
-        row = np.where(rng.random(n_items) < 0.85, pat, rng.random(n_items) < 0.1)
-        txns.append(np.nonzero(row)[0].tolist() or [0])
-    return txns, n_items
 
 
 def _levels_snapshot(res):
@@ -42,27 +29,11 @@ def _assert_levels_equal(a, b, ctx):
                                       err_msg=f"{ctx}: counts at k={k}")
 
 
-@pytest.mark.parametrize("algo", ALGOS)
-def test_cross_impl_equivalence(algo):
-    """mine() produces identical levels for every counting impl."""
-    txns, n_items = _dataset()
-    ref = None
-    for impl in IMPLS:
-        rt = MapReduceRuntime(impl=impl, autotune=False)
-        res = mine(txns, n_items=n_items, min_sup=0.3, algorithm=algo,
-                   runtime=rt)
-        snap = _levels_snapshot(res)
-        if ref is None:
-            ref = snap
-        else:
-            _assert_levels_equal(ref, snap, f"{algo}/{impl}")
-
-
 @pytest.mark.parametrize("algo", ["spc", "vfpc", "optimized_vfpc",
                                   "optimized_etdpc"])
 def test_fused_matches_unfused(algo):
     """The fused (device-filter) path and the legacy unfused path agree."""
-    txns, n_items = _dataset(seed=3)
+    txns, n_items = planted_baskets(seed=3)
     rt_f = MapReduceRuntime(autotune=False)
     res_f = mine(txns, n_items=n_items, min_sup=0.3, algorithm=algo,
                  runtime=rt_f, pipeline=True)
@@ -78,7 +49,7 @@ def test_fused_matches_unfused(algo):
 
 def test_phase_count_filtered_matches_phase_count():
     """Runtime-level: fused keep mask == host-side threshold on plain counts."""
-    txns, n_items = _dataset(seed=7)
+    txns, n_items = planted_baskets(seed=7)
     db = pack_itemsets(txns, n_items)
     rt = MapReduceRuntime(autotune=False)
     sharded = rt.scatter_db(db, n_items=n_items)
@@ -98,7 +69,7 @@ def test_phase_count_filtered_matches_phase_count():
 
 
 def test_count_future_is_async_handle():
-    txns, n_items = _dataset(seed=11)
+    txns, n_items = planted_baskets(seed=11)
     db = pack_itemsets(txns, n_items)
     rt = MapReduceRuntime(autotune=False)
     sharded = rt.scatter_db(db, n_items=n_items)
@@ -154,7 +125,7 @@ def test_autotuner_caches_in_process_and_on_disk(tmp_path, monkeypatch):
 def test_overlap_stat_accumulates_when_speculating():
     """A run that speculates records the phase's spec time; overlap_seconds
     only grows when a job was genuinely in flight (never negative)."""
-    txns, n_items = _dataset(seed=5, n=150)
+    txns, n_items = planted_baskets(seed=5, n=150)
     rt = MapReduceRuntime(autotune=False)
     res = mine(txns, n_items=n_items, min_sup=0.25,
                algorithm="optimized_vfpc", runtime=rt, pipeline=True)
