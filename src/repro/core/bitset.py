@@ -286,6 +286,30 @@ def jpack_bits(bits: jax.Array) -> jax.Array:
     return (words << shifts).sum(axis=-1).astype(jnp.uint32)
 
 
+def jvertical_pack(rows: jax.Array, n_items: int) -> jax.Array:
+    """Device-side :func:`vertical_pack`: ``(n, W)`` uint32 rows →
+    ``(n_items + 1, Tw)`` uint32, the same bytes (the valid row last, zero
+    bits past row ``n`` in the last word).
+
+    Rows are zero-padded to ``Tw·32`` and viewed as ``(W, 32 rows, Tw)``;
+    word ``tw`` of item ``w·32 + b`` is then ``Σ_r bit b of row tw·32+r << r``,
+    a 32×32 bit transpose per (word, row block), with no ``(n, W·32)``
+    bit matrix in between."""
+    n, W = rows.shape
+    Tw = -(-n // WORD_BITS)
+    x = jnp.pad(rows.astype(jnp.uint32), ((0, Tw * WORD_BITS - n), (0, 0)))
+    x = x.reshape(Tw, WORD_BITS, W).transpose(2, 1, 0)          # (W, r, Tw)
+    shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)
+    bits = (x[:, None] >> shifts[None, :, None, None]) & jnp.uint32(1)
+    words = (bits << shifts[None, None, :, None]).sum(
+        axis=2, dtype=jnp.uint32)                               # (W, b, Tw)
+    items = words.reshape(W * WORD_BITS, Tw)[:n_items]
+    row = jnp.arange(Tw * WORD_BITS).reshape(Tw, WORD_BITS)
+    valid = ((row < n).astype(jnp.uint32) << shifts).sum(
+        axis=1, dtype=jnp.uint32)
+    return jnp.concatenate([items, valid[None]], axis=0)
+
+
 def jsubset_matrix(cands: jax.Array, txns: jax.Array) -> jax.Array:
     """(C, W) x (T, W) → (C, T) bool: candidate ⊆ transaction."""
     c = cands[:, None, :]

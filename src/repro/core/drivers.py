@@ -211,11 +211,14 @@ def mine(transactions=None, *, db_masks: np.ndarray | None = None,
     bytes_to_device_start = runtime.stats.bytes_to_device
     exchange_bytes_start = runtime.stats.exchange_bytes
     pack_start = runtime.stats.pack_seconds
+    layouts_start = runtime.stats.device_layouts
     with tracer.span("mine.scatter", n_txns=n_txns, n_words=n_words,
                      shards=runtime.n_data_shards) as scatter_span:
         db_sharded = runtime.scatter_db(db_masks, n_items=n_items)
         scatter_span.set(
-            pack_seconds=runtime.stats.pack_seconds - pack_start)
+            pack_seconds=runtime.stats.pack_seconds - pack_start,
+            layout=("device" if runtime.stats.device_layouts > layouts_start
+                    else "host"))
     # re-pin: an "auto" runtime may have switched impl at scatter time
     controller.set_count_context(n_txns=n_txns, n_words=n_words,
                                  impl=runtime.impl,

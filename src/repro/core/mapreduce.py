@@ -53,7 +53,7 @@ from repro.kernels.autotune import defaults_fit, tuned_blocks
 from repro.obs.metrics import get_registry
 
 from .counting import local_counts, local_counts_vertical
-from .bitset import WORD_BITS, floor_log2
+from .bitset import WORD_BITS, floor_log2, jvertical_pack
 
 IMPLS = ("jnp", "matmul", "pallas", "pallas_interpret",
          "matmul_pallas", "matmul_pallas_interpret",
@@ -75,6 +75,7 @@ class RuntimeStats:
     repartitions: int = 0       # elastic mesh re-layouts (DESIGN.md §11)
     scatter_seconds: float = 0.0  # host time spent (re-)placing the database
     pack_seconds: float = 0.0   # of it, building the vertical per-shard bitmaps
+    device_layouts: int = 0     # scatters whose bitmaps each chip built itself
     index_seconds: float = 0.0  # building the vertical forms' item index
 
     def __setattr__(self, name, value):
@@ -202,6 +203,7 @@ class MapReduceRuntime:
         self.stats = RuntimeStats()
         self._shape_cache: set = set()
         self._jitted = {}
+        self._layouts = {}
         self._n_items: int | None = None
         self._db_masks: np.ndarray | None = None  # host copy for re-scatter
 
@@ -236,9 +238,10 @@ class MapReduceRuntime:
         """Zero-pad rows to the shard multiple and place shards on devices.
 
         Horizontal impls return the (N, W) row-sharded matrix; the vertical
-        impl returns (d, I+1, Tw) per-shard item-major bitmaps (built host-side
-        once — the InputFormat step of the job).  The unpadded host copy is
-        retained for :meth:`repartition`/:meth:`rescatter`."""
+        impl returns (d, I+1, Tw) per-shard item-major bitmaps, each built on
+        its own chip from its row split (the InputFormat step of the job).
+        The unpadded host copy is retained for
+        :meth:`repartition`/:meth:`rescatter`."""
         self._db_masks = np.asarray(db_masks, dtype=np.uint32)
         if n_items is not None:
             self._n_items = n_items
@@ -246,7 +249,6 @@ class MapReduceRuntime:
 
     def _scatter_current(self):
         """(Re-)place the retained database on the current mesh."""
-        from .bitset import vertical_pack
         db_masks = self._db_masks
         n, w = db_masks.shape
         t0 = time.perf_counter()
@@ -271,22 +273,37 @@ class MapReduceRuntime:
         if pad:
             db_masks = np.concatenate(
                 [db_masks, np.zeros((pad, w), np.uint32)], axis=0)
-        if self.vertical:
-            assert self._n_items is not None, "vertical impl needs n_items"
-            per = db_masks.shape[0] // d
-            t_pack = time.perf_counter()
-            host = np.stack([
-                vertical_pack(db_masks[i * per:(i + 1) * per], self._n_items)
-                for i in range(d)])                      # (d, I+1, Tw)
-            self.stats.pack_seconds += time.perf_counter() - t_pack
-            spec = P("data", None, None)
-        else:
-            host, spec = db_masks, P("data", None)
-        out = jax.device_put(host, NamedSharding(self.mesh, spec))
+        assert not self.vertical or self._n_items is not None, \
+            "vertical impl needs n_items"
+        out = jax.device_put(db_masks,
+                             NamedSharding(self.mesh, P("data", None)))
         # sharded over data, so every other mesh axis holds a copy
-        self.stats.bytes_to_device += host.nbytes * (self.mesh.size // d)
+        self.stats.bytes_to_device += db_masks.nbytes * (self.mesh.size // d)
+        if self.vertical:
+            # block here, so the layout's time (and the transfer it waits
+            # on) is the scatter's, not the first count job's
+            t_pack = time.perf_counter()
+            out = jax.block_until_ready(self._vertical_layout(out))
+            self.stats.pack_seconds += time.perf_counter() - t_pack
+            self.stats.device_layouts += 1
         self.stats.scatter_seconds += time.perf_counter() - t0
         return out
+
+    def _vertical_layout(self, rows: jax.Array) -> jax.Array:
+        """``(n_pad, W)`` rows sharded over ``data`` → ``(d, I+1, Tw)``: one
+        shard_map in which every chip lays out its own split as
+        :func:`~repro.core.bitset.vertical_pack` would, all at once."""
+        per = rows.shape[0] // self.n_data_shards
+        key = (self.mesh, per, rows.shape[1], self._n_items)
+        if key not in self._layouts:
+            n_items = self._n_items
+
+            def vertical_layout(local):
+                return jvertical_pack(local, n_items)[None]
+            self._layouts[key] = jax.jit(jax.shard_map(
+                vertical_layout, mesh=self.mesh, in_specs=P("data", None),
+                out_specs=P("data", None, None), check_vma=False))
+        return self._layouts[key](rows)
 
     def rescatter(self):
         """Re-place shards from the host copy on the *same* mesh — the
